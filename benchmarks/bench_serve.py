@@ -254,6 +254,7 @@ def _paged_kernel(arch: str, n_requests: int, prompt_len: int,
     """
     import jax.numpy as jnp
 
+    from repro.kernels.ops import kernel_mode
     from repro.models import transformer as tf
 
     cfg = reduced_config(arch)
@@ -290,13 +291,14 @@ def _paged_kernel(arch: str, n_requests: int, prompt_len: int,
     toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (1, 16)), jnp.int32)
     for b in range(B):
         cache = tf.prefill_chunk(params, cache, toks, tables[b],
-                                 jnp.int32(0), cfg, kernels=False)
+                                 jnp.int32(0), cfg)
     vt = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, 4)), jnp.int32)
     pos = jnp.full((B,), 16, jnp.int32)
 
     def verify_fn(pk):
         return jax.jit(lambda c, t, i: tf.verify_step(
-            params, c, t, i, cfg, tables=tables, kernels=pk)[0])
+            params, c, t, i, cfg, tables=tables,
+            kernels=kernel_mode(pk))[0])
 
     t_k = timeit(verify_fn(True), cache, vt, pos)
     t_j = timeit(verify_fn(False), cache, vt, pos)

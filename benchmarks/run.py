@@ -22,6 +22,8 @@ import subprocess
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def _parse_derived(derived: str) -> dict:
     """Split a ``k=v;k=v`` derived string into typed metric columns so the
@@ -73,6 +75,7 @@ def _write_json(path: str) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="trim grids for a quick pass")

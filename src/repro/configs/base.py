@@ -147,12 +147,13 @@ class ServeConfig:
     regardless.
     ``paged_kernels``: attention implementation for the paged serve path
     (decode / speculative verify / chunked prefill).  None (default)
-    auto-detects: the flash-decode Pallas kernels
+    picks the compiled flash-decode Pallas kernels
     (``kernels/paged_attention.py`` — one pass over each slot's block
     table, no dense gathered KV copy) on TPU, the jnp
     gather-then-softmax oracle path elsewhere.  True forces the kernels
     (interpret mode off-TPU — the validation configuration), False
-    forces the jnp path.  Resolved once at engine construction; both
+    forces the jnp path.  Resolved once at engine construction by
+    ``kernels.ops.kernel_mode``; both
     choices keep the one-compilation-per-engine contract and the
     sketched two-span fold_base == 0 bitwise anchor.
     """

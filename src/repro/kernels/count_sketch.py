@@ -42,14 +42,12 @@ def _cs_kernel(x_ref, h_ref, s_ref, o_ref, *, bJ: int):
                                              "interpret"))
 def count_sketch(x: jax.Array, h: jax.Array, s: jax.Array, J: int,
                  bB: int = 128, bI: int = 512, bJ: int = 256,
-                 interpret: bool | None = None) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """x: (B, I) -> (B, J) count sketch with shared hash (h, s).
 
-    interpret=None auto-detects the backend: compiled on TPU, interpret
-    mode (kernel body in Python — bit-identical block semantics) off-TPU.
+    Compiled for the TPU unless ``interpret`` asks for interpret mode
+    (kernel body in Python — bit-identical block semantics).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, I = x.shape
     bB = min(bB, B)
     bI = min(bI, I)

@@ -17,8 +17,8 @@ MXU contraction.
                 in-kernel — grid (N/bN, T/bT), bucket products computed
                 once per query block and parked in VMEM scratch.
 
-Both run with ``interpret=None`` auto-detect (compiled on TPU, interpret
-elsewhere) and are validated against kernels/ref.py oracles that
+Both compile for the TPU unless ``interpret=True`` asks for interpret
+mode, and are validated against kernels/ref.py oracles that
 delegate to serve/kv_sketch.py — kernel and serve path share
 sketch/hashing.py, so the hash arithmetic matches bitwise.
 """
@@ -89,12 +89,10 @@ def _scores_kernel(q_ref, t_ref, c_ref, o_ref, qa_ref, *,
 @functools.partial(jax.jit, static_argnames=("bN", "bC", "interpret"))
 def tail_fold(rows: jax.Array, positions: jax.Array, tail: jax.Array,
               coeffs: jax.Array, *, bN: int = 256, bC: int = 256,
-              interpret: bool | None = None) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     """Accumulate ``rows`` (N, D) at absolute ``positions`` (N,) int32
     into ``tail`` (Z, C, D) f32.  Returns the new (Z, C, D) table.
     D is the flattened feature axis (K * head_dim for KV rows)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     N, D = rows.shape
     Z, C, _ = tail.shape
     bN = min(bN, N)
@@ -128,13 +126,11 @@ def tail_fold(rows: jax.Array, positions: jax.Array, tail: jax.Array,
 @functools.partial(jax.jit, static_argnames=("T", "bN", "bT", "interpret"))
 def tail_scores(q: jax.Array, tail_k: jax.Array, coeffs: jax.Array, *,
                 T: int, bN: int = 128, bT: int = 256,
-                interpret: bool | None = None) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """Median-of-rows tail score estimates: (N, T) where
     out[n, t] ~= q[n] . key_row(t) for folded positions t.  q: (N, D);
     tail_k: (Z, C, D); unscaled and unmasked — the caller applies the
     softmax scale and the fold_base live mask."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     N, D = q.shape
     Z, C, _ = tail_k.shape
     bN = min(bN, N)

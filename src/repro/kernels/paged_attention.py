@@ -89,7 +89,7 @@ def _attend_block(tbl_ref, st_ref, fb_ref, q_ref, k_ref, v_ref,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     tables: jax.Array, start: jax.Array,
                     fold_base: jax.Array, *,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """Flash-decode attention through per-slot block tables.
 
     q: (B, Sq, K, R, hd); k_pool/v_pool: (NB, bs, K, hd) shared pool;
@@ -97,11 +97,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     start: (B,) int32 per-slot position of query row 0; fold_base: (B,)
     int32 lower visibility bound (zeros when no span is folded).
 
-    interpret=None auto-detects: compiled on TPU, interpret elsewhere.
+    Compiled for the TPU unless ``interpret`` asks for interpret mode.
     Returns f32 (m, l, acc): (B, K, R, Sq) x2 and (B, K, R, Sq, hd).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, Sq, K, R, hd = q.shape
     NB, bs = k_pool.shape[0], k_pool.shape[1]
     nb_slot = tables.shape[1]

@@ -26,7 +26,7 @@ freshly written tables — the strict accumulate->query dependency makes a
 single-grid formulation impossible without violating Pallas's
 consecutive-output-revisit rule.
 
-``interpret=None`` auto-detects: compiled on TPU, interpret elsewhere.
+Compiled for the TPU unless ``interpret=True`` asks for interpret mode.
 ``kernels/ref.py:sketch_update_ref`` is the pure-jnp oracle (bit-matching
 hash arithmetic — both paths share sketch/hashing.py).
 """
@@ -126,15 +126,13 @@ def sketch_update(g: jax.Array, m_table: jax.Array, v_table: jax.Array,
                   coeffs_m: jax.Array, coeffs_v: jax.Array, *,
                   b1: float = 0.9, b2: float = 0.95,
                   bI: int = 512, bC: int = 256,
-                  interpret: bool | None = None):
+                  interpret: bool = False):
     """Fused moment update + estimate for one flat gradient leaf.
 
     g: (n,) — any float dtype, accumulated in f32.
     m_table / v_table: (R, C) f32; coeffs_*: (R, 4) uint32.
     Returns (new_m (R, C), new_v (R, C), m_hat (n,), v_hat (n,)).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = g.shape[0]
     R, C = m_table.shape
     bI = min(bI, n)

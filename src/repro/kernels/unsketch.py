@@ -34,13 +34,11 @@ def _unsketch_kernel(y_ref, h_ref, s_ref, o_ref, *, bJ: int):
 @functools.partial(jax.jit, static_argnames=("bB", "bI", "bJ", "interpret"))
 def unsketch(y: jax.Array, h: jax.Array, s: jax.Array,
              bB: int = 128, bI: int = 512, bJ: int = 256,
-             interpret: bool | None = None) -> jax.Array:
+             interpret: bool = False) -> jax.Array:
     """y: (B, J), hash tables over I entries -> (B, I) estimates.
 
-    interpret=None auto-detects: compiled on TPU, interpret mode off-TPU.
+    Compiled for the TPU unless ``interpret`` asks for interpret mode.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, J = y.shape
     I = h.shape[0]
     bB = min(bB, B)

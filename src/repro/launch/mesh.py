@@ -7,16 +7,10 @@ before importing jax; real deployments get the same mesh over TPU chips.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types; older releases are Auto-only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -43,6 +43,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.obs import ServeObserver, Tracer
 from repro.serve.frontend import AsyncServeEngine
@@ -73,6 +74,28 @@ def make_request_stream(cfg, rng: np.random.RandomState, n_requests: int,
             top_k=top_k if sampled else 0,
             seed=int(rng.randint(1 << 30)) if sampled else None))
     return reqs
+
+
+def long_context_request(cfg, rng: np.random.RandomState, rid: int,
+                         prompt_len: int, max_new: int) -> Request:
+    """One long random prompt: with a sketched KV window shorter than
+    it, prefill folds blocks into the slot's FCS tail and decode attends
+    exact window + sketched tail."""
+    return Request(
+        rid=rid,
+        tokens=rng.randint(0, cfg.vocab_size, (prompt_len,)).astype(np.int32),
+        max_new=max_new)
+
+
+def init_serve_params(cfg, seed: int):
+    """The serve drivers' random weights: the seed's first split key (the
+    second is left for data), initialised as one compiled program so a
+    full-width config fits the chip that holds its bf16 weights."""
+    # independent keys: reusing the params-init key for prompt generation
+    # correlates weights with data (and made every run's prompts identical
+    # to its init) — split once, use each stream exactly once.
+    k_params, _ = jax.random.split(jax.random.PRNGKey(seed))
+    return M.init_params_jit(k_params, cfg)
 
 
 async def stream_poisson(front: AsyncServeEngine, reqs, rate: float,
@@ -120,6 +143,7 @@ async def stream_poisson(front: AsyncServeEngine, reqs, rate: float,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="gemma-2b")
     ap.add_argument("--requests", type=int, default=24)
@@ -199,11 +223,7 @@ def main():
     args = ap.parse_args()
 
     cfg = get_config(args.arch) if args.full else reduced_config(args.arch)
-    # independent keys: reusing the params-init key for prompt generation
-    # correlates weights with data (and made every run's prompts identical
-    # to its init) — split once, use each stream exactly once.
-    k_params, _ = jax.random.split(jax.random.PRNGKey(args.seed))
-    params = M.init_params(k_params, cfg)
+    params = init_serve_params(cfg, args.seed)
     serve = dataclasses.replace(
         cfg.serve, max_batch=args.max_batch, max_seq=args.max_seq,
         admit_threshold=args.admit_threshold, prefix_block=args.prefix_len,
@@ -236,11 +256,9 @@ def main():
     if args.long_context:
         assert args.kv_sketch_window > 0, "--long-context needs a window"
         S = min(args.long_context, args.max_seq - args.max_new)
-        rng_lc = np.random.RandomState(args.seed + 2)
-        reqs.append(Request(
-            rid=len(reqs),
-            tokens=rng_lc.randint(0, cfg.vocab_size, (S,)).astype(np.int32),
-            max_new=args.max_new))
+        reqs.append(long_context_request(
+            cfg, np.random.RandomState(args.seed + 2), len(reqs), S,
+            args.max_new))
 
     t0 = time.time()
     if args.arrival_rate > 0:
@@ -263,8 +281,9 @@ def main():
               f"p99={np.percentile(ttfts, 99)*1e3:.0f}ms "
               f"over {len(ttfts)} first tokens")
     if cfg.family in KV_FAMILIES:
-        print(f"paged attention: "
-              f"{'pallas kernels' if sched.use_kernels else 'jnp'} "
+        attn = (f"{sched.kernels} pallas kernel" if sched.use_kernels
+                else "jnp")
+        print(f"paged attention: {attn} "
               f"(--paged-kernels {args.paged_kernels})")
         if sched.sketch_on:
             print(f"kv sketch: window={serve.kv_sketch_window} rows "

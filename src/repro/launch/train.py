@@ -13,10 +13,12 @@ from __future__ import annotations
 import argparse
 
 from repro.configs.registry import ARCH_IDS, get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.train.loop import train
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="yi-9b")
     ap.add_argument("--steps", type=int, default=100)

@@ -122,10 +122,12 @@ def _sketched_two_span(o, qg, kt, vt, win, tail, sketch, scale):
     return jnp.where(sel, merged, o)
 
 
-def _kernel_paged_attention(qg, k, v, tables, start, tail, sketch):
+def _kernel_paged_attention(qg, k, v, tables, start, tail, sketch,
+                            kernels):
     """Flash-decode kernel path shared by the three paged shapes
     (kernels/paged_attention.py): attend straight through the block
-    table — the dense gathered KV copy never materializes.  qg:
+    table — the dense gathered KV copy never materializes.  ``kernels``
+    is the resolved ``kops.kernel_mode`` ("compiled" / "interpret").  qg:
     (B, Sq, K, R, hd); k/v: the updated (NB, bs, K, hd) pools; start:
     (B,) position of each slot's query row 0.  With ``tail``/``sketch``
     the kernel covers the exact window [fold_base, start + i] and the
@@ -137,7 +139,7 @@ def _kernel_paged_attention(qg, k, v, tables, start, tail, sketch):
     fb = (sketch["fold_base"] if tail is not None
           else jnp.zeros((B,), jnp.int32))
     m_e, l_e, acc_e = kops.paged_attention_op(qg, k, v, tables, start, fb,
-                                              use_pallas=True)
+                                              kernels=kernels)
     o = acc_e / jnp.maximum(l_e, 1e-30)[..., None]      # (B,K,R,Sq,hd)
     if tail is not None:
         from repro.serve import kv_sketch as _kvs
@@ -259,7 +261,7 @@ def decode_attention(p: dict, x: jax.Array, cfg: ModelConfig,
                      tables: Optional[jax.Array] = None,
                      tail: Optional[dict] = None,
                      sketch: Optional[dict] = None,
-                     use_kernel: Optional[bool] = None
+                     kernels: Optional[str] = None
                      ) -> Tuple[jax.Array, dict]:
     """Single-token decode against a KV cache.
 
@@ -281,7 +283,7 @@ def decode_attention(p: dict, x: jax.Array, cfg: ModelConfig,
     """
     if tables is not None:
         return _paged_decode_attention(p, x, cfg, cache, index, tables,
-                                       tail, sketch, use_kernel)
+                                       tail, sketch, kernels)
     B, one, _ = x.shape
     hd = cfg.resolved_head_dim
     H, K = cfg.num_heads, cfg.num_kv_heads
@@ -319,17 +321,16 @@ def _paged_decode_attention(p: dict, x: jax.Array, cfg: ModelConfig,
                             tables: jax.Array,
                             tail: Optional[dict] = None,
                             sketch: Optional[dict] = None,
-                            use_kernel: Optional[bool] = None
+                            kernels: Optional[str] = None
                             ) -> Tuple[jax.Array, dict]:
     """Paged single-token decode: scatter each slot's new KV row through
     its block table, then attend — in one flash-decode Pallas pass over
-    the table (``use_kernel``, default on TPU) or by gathering the
-    slot's blocks dense and softmaxing in jnp (the oracle path, default
-    elsewhere).  See decode_attention.
+    the table (``kernels`` = "compiled" / "interpret", a resolved
+    ``kops.kernel_mode``) or, with ``kernels`` None, by gathering the
+    slot's blocks dense and softmaxing in jnp (the oracle path).  See
+    decode_attention.
     With ``tail``/``sketch`` (serve/kv_sketch.py) the attention becomes
     two-span: exact over [fold_base, index], sketched over [0, fold_base)."""
-    if use_kernel is None:
-        use_kernel = kops.default_use_pallas()
     B, _, _ = x.shape
     hd = cfg.resolved_head_dim
     H, K = cfg.num_heads, cfg.num_kv_heads
@@ -352,9 +353,10 @@ def _paged_decode_attention(p: dict, x: jax.Array, cfg: ModelConfig,
     v = shard(v, "kv_blocks", None, "kv_heads", None)
     qg = q.reshape(B, 1, K, R, hd)
     scale = 1.0 / math.sqrt(hd)
-    if use_kernel:
+    if kernels:
         o = _kernel_paged_attention(qg, k, v, tables,
-                                    index.astype(jnp.int32), tail, sketch)
+                                    index.astype(jnp.int32), tail, sketch,
+                                    kernels)
     else:
         # gather the slot's logical KV row; invalid blocks read as zeros
         # and sit at positions the per-slot causal mask never exposes
@@ -382,7 +384,7 @@ def verify_attention(p: dict, x: jax.Array, cfg: ModelConfig,
                      cache: dict, index: jax.Array, tables: jax.Array,
                      tail: Optional[dict] = None,
                      sketch: Optional[dict] = None,
-                     use_kernel: Optional[bool] = None
+                     kernels: Optional[str] = None
                      ) -> Tuple[jax.Array, dict]:
     """Multi-query paged decode (speculative verify).
 
@@ -422,14 +424,13 @@ def verify_attention(p: dict, x: jax.Array, cfg: ModelConfig,
     v = shard(v, "kv_blocks", None, "kv_heads", None)
     qg = q.reshape(B, C, K, R, hd)
     scale = 1.0 / math.sqrt(hd)
-    if use_kernel is None:
-        use_kernel = kops.default_use_pallas()
-    if use_kernel:
+    if kernels:
         # kernel row i of slot b sees key positions <= index[b] + i —
         # identical per-row math to a single-token decode at that
         # position, the bitwise spec-identity anchor
         o = _kernel_paged_attention(qg, k, v, tables,
-                                    index.astype(jnp.int32), tail, sketch)
+                                    index.astype(jnp.int32), tail, sketch,
+                                    kernels)
     else:
         kt = jnp.take(k, tables, axis=0, mode="fill", fill_value=0)
         vt = jnp.take(v, tables, axis=0, mode="fill", fill_value=0)
@@ -455,7 +456,7 @@ def chunk_attention(p: dict, x: jax.Array, cfg: ModelConfig,
                     cache: dict, table: jax.Array, start: jax.Array,
                     tail: Optional[dict] = None,
                     sketch: Optional[dict] = None,
-                    use_kernel: Optional[bool] = None
+                    kernels: Optional[str] = None
                     ) -> Tuple[jax.Array, dict]:
     """Multi-token chunk against the paged slot KV (chunked prefill).
 
@@ -490,12 +491,10 @@ def chunk_attention(p: dict, x: jax.Array, cfg: ModelConfig,
     v = shard(v, "kv_blocks", None, "kv_heads", None)
     qg = q.reshape(1, C, K, R, hd)
     scale = 1.0 / math.sqrt(hd)
-    if use_kernel is None:
-        use_kernel = kops.default_use_pallas()
-    if use_kernel:
+    if kernels:
         o = _kernel_paged_attention(qg, k, v, table[None],
                                     jnp.reshape(start, (1,)).astype(
-                                        jnp.int32), tail, sketch)
+                                        jnp.int32), tail, sketch, kernels)
     else:
         ks = jnp.take(k, table, axis=0, mode="fill", fill_value=0)
         vs = jnp.take(v, table, axis=0, mode="fill", fill_value=0)

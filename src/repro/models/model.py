@@ -20,9 +20,15 @@ from repro.models.transformer import (  # re-exports (public API)
 )
 
 __all__ = [
-    "init_params", "forward", "loss_fn", "decode_step", "prefill",
-    "init_cache", "input_specs", "param_specs", "cache_specs",
+    "init_params", "init_params_jit", "forward", "loss_fn", "decode_step",
+    "prefill", "init_cache", "input_specs", "param_specs", "cache_specs",
 ]
+
+# ``init_params`` as ONE compiled program, for the entry points: each
+# leaf's f32 normal draw fuses with its bf16 cast, so no f32 copy of a
+# whole stacked leaf ever lands on the device — what lets a full-width
+# config initialise on the one chip that holds its bf16 weights.
+init_params_jit = jax.jit(tf.init_params, static_argnums=1)
 
 
 def _sds(shape, dtype):

@@ -108,26 +108,26 @@ def _dense_block(p_l, x, cfg: ModelConfig, positions, cache_l, index, mode,
     """One attention+FFN (or attention+MoE) block. Returns (x, aux, cache).
     ``tail_l``/``sketch``: per-layer FCS tail tables + fold state for
     two-span long-context decode (serve/kv_sketch.py); read-only here.
-    ``kernels``: paged modes — True routes attention through the
-    flash-decode Pallas kernel, False through the jnp gather path, None
-    auto-detects (kernel on TPU)."""
+    ``kernels``: paged modes — a resolved ``kops.kernel_mode``:
+    "compiled" / "interpret" route attention through the flash-decode
+    Pallas kernel, None through the jnp gather path."""
     h = ly.rms_norm(x, p_l["norm1"], cfg.norm_eps)
     new_cache = None
     if mode == "decode":
         a, new_cache = ly.decode_attention(p_l["attn"], h, cfg, cache_l,
                                            index, tables=tables,
                                            tail=tail_l, sketch=sketch,
-                                           use_kernel=kernels)
+                                           kernels=kernels)
     elif mode == "verify":
         a, new_cache = ly.verify_attention(p_l["attn"], h, cfg, cache_l,
                                            index, tables, tail=tail_l,
                                            sketch=sketch,
-                                           use_kernel=kernels)
+                                           kernels=kernels)
     elif mode == "chunk":
         a, new_cache = ly.chunk_attention(p_l["attn"], h, cfg, cache_l,
                                           tables, index, tail=tail_l,
                                           sketch=sketch,
-                                          use_kernel=kernels)
+                                          kernels=kernels)
     else:
         a = ly.causal_attention(p_l["attn"], h, cfg, positions)
         if mode == "prefill":
@@ -156,7 +156,7 @@ def forward(params: Params, x: jax.Array, cfg: ModelConfig,
             index: Optional[jax.Array] = None,
             tables: Optional[jax.Array] = None,
             sketch: Optional[dict] = None,
-            kernels: Optional[bool] = None
+            kernels: Optional[str] = None
             ) -> Tuple[jax.Array, jax.Array, Optional[dict]]:
     """x: embedded inputs (B, S, d).  Returns (hidden, aux_loss, cache).
 
@@ -164,10 +164,11 @@ def forward(params: Params, x: jax.Array, cfg: ModelConfig,
     int32, "onehot": (Z, T, C)} — enables two-span decode against the
     cache's "tail" FCS tables (serve/kv_sketch.py).
 
-    ``kernels`` (paged modes only): static attention-implementation
-    switch — True runs the flash-decode paged Pallas kernel
-    (kernels/paged_attention.py; interpret mode off-TPU), False the jnp
-    gather-then-softmax path, None auto-detects (kernel on TPU).
+    ``kernels`` (paged modes only): static attention implementation, a
+    resolved ``kops.kernel_mode`` — "compiled" runs the flash-decode
+    paged Pallas kernel (kernels/paged_attention.py), "interpret" the
+    same kernel in interpret mode (CPU validation), None the jnp
+    gather-then-softmax path.
 
     Modes: "train" / "prefill" (full-sequence), "decode" (single token per
     slot against the cache — paged through per-slot block ``tables`` when
@@ -438,7 +439,7 @@ def decode_step(params: Params, cache: dict, tokens: jax.Array,
                 index: jax.Array, cfg: ModelConfig,
                 tables: Optional[jax.Array] = None,
                 sketch: Optional[dict] = None,
-                kernels: Optional[bool] = None
+                kernels: Optional[str] = None
                 ) -> Tuple[jax.Array, dict]:
     """tokens: (B, 1) int32.  Returns (logits (B, Vp) f32, new cache).
     ``tables``: optional (B, blocks_per_slot) block tables — paged-KV
@@ -457,7 +458,7 @@ def decode_step(params: Params, cache: dict, tokens: jax.Array,
 def verify_step(params: Params, cache: dict, tokens: jax.Array,
                 index: jax.Array, cfg: ModelConfig, tables: jax.Array,
                 sketch: Optional[dict] = None,
-                kernels: Optional[bool] = None
+                kernels: Optional[str] = None
                 ) -> Tuple[jax.Array, dict]:
     """Speculative-decode verification: score C tokens per slot in ONE
     compiled multi-query decode against the paged pool.
@@ -493,7 +494,7 @@ def prefill(params: Params, batch: dict, cfg: ModelConfig
 def prefill_chunk(params: Params, cache: dict, tokens: jax.Array,
                   table: jax.Array, start: jax.Array, cfg: ModelConfig,
                   sketch: Optional[dict] = None,
-                  kernels: Optional[bool] = None) -> dict:
+                  kernels: Optional[str] = None) -> dict:
     """Chunked prefill step: write KV rows for absolute positions
     [start, start + C) into the paged pool through the slot's
     (blocks_per_slot,) block-table row ``table``, attending the chunk

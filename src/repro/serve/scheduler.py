@@ -429,13 +429,13 @@ class SlotScheduler:
         self.temperature = float(temperature)   # default for requests
         self.is_kv = cfg.family in KV_FAMILIES
         sv = self.serve
-        # paged-attention implementation, resolved ONCE to a static bool
-        # (None = auto: Pallas kernels on TPU, jnp gather path elsewhere)
-        # and baked into every compiled chunk below — layers never
-        # re-detect, so the chunks stay one-compilation-per-engine
-        self.use_kernels = (kops.default_use_pallas()
-                            if sv.paged_kernels is None
-                            else bool(sv.paged_kernels)) and self.is_kv
+        # paged-attention implementation, resolved ONCE by
+        # kops.kernel_mode ("compiled" / "interpret" Pallas kernel, or
+        # None for the jnp gather path) and baked into every compiled
+        # chunk below as a static — layers never re-detect, so the
+        # chunks stay one-compilation-per-engine
+        self.kernels = (kops.kernel_mode(sv.paged_kernels)
+                        if self.is_kv else None)
         B = sv.max_batch
         # speculative decode: an explicit draft wins; else derive one per
         # the serve knobs (None when spec_k == 0 or the family has no KV)
@@ -609,13 +609,13 @@ class SlotScheduler:
             else:
                 self._prefill_chunk = jax.jit(
                     functools.partial(tf.prefill_chunk, cfg=cfg,
-                                      kernels=self.use_kernels),
+                                      kernels=self.kernels),
                     donate_argnums=(1,))
                 if self.draft is not None:
                     self._draft_prefill_chunk = jax.jit(
                         functools.partial(tf.prefill_chunk,
                                           cfg=self.draft.cfg,
-                                          kernels=self.use_kernels),
+                                          kernels=self.kernels),
                         donate_argnums=(1,))
             # copy-on-write block fork: copy one physical block's rows
             # (target AND draft pools) to a fresh block, device-side
@@ -686,7 +686,7 @@ class SlotScheduler:
         is_kv = self.is_kv
         sample = self._make_sampler()
         sketch_on = self.sketch_on
-        kernels = self.use_kernels
+        kernels = self.kernels
         if sketch_on:
             onehot, coeffs = self.tail_onehot, self.tail_coeffs
             fold_cap = self.fold_cap
@@ -780,7 +780,7 @@ class SlotScheduler:
         return build_spec_chunk(self.cfg, self.draft.cfg,
                                 self.serve.decode_chunk, self.spec_max,
                                 self._make_sampler(), sketch=sketch,
-                                kernels=self.use_kernels)
+                                kernels=self.kernels)
 
     def _make_sketch_prefill(self, model_cfg: ModelConfig, is_draft: bool):
         """Jitted sketched prefill chunk: the legacy chunk plus the
@@ -791,7 +791,7 @@ class SlotScheduler:
         chunk's (the two-span select picks the exact output and the KV
         scatter is untouched)."""
         onehot = self.tail_onehot
-        kernels = self.use_kernels
+        kernels = self.kernels
 
         def spc(params, pool, tail_full, tok, table, start, slot,
                 fold_base):
@@ -1030,8 +1030,11 @@ class SlotScheduler:
             tok = np.zeros((1, bucket), np.int32)
             tok[0, :len(seg)] = seg
             t0 = time.perf_counter()
+            # jnp.array copies ``row``: the loop rewrites it in place
+            # while the dispatched chunk may still read it, and on the CPU
+            # jnp.asarray of an aligned numpy array aliases its memory
             cache = self._prefill_one(cache, jnp.asarray(tok),
-                                      jnp.asarray(row), off, slot,
+                                      jnp.array(row), off, slot,
                                       fold_base)
             if self.obs is not None:
                 self.obs.prefill_span(slot, off, len(seg),
@@ -1042,7 +1045,7 @@ class SlotScheduler:
             n_elig = max(0, (end - W) // bs) - first_lblk
             while n_elig > 0:
                 k = min(n_elig, self.prefill_fold_cap // bs)
-                cache = self._fold_fn(cache, jnp.asarray(row),
+                cache = self._fold_fn(cache, jnp.array(row),
                                       jnp.int32(fold_base),
                                       jnp.int32(k * bs), jnp.int32(slot))
                 # sentinel the folded entries BEFORE freeing: a freed
@@ -1557,6 +1560,12 @@ class SlotScheduler:
             # so no other slot's table can claim a stale-mapped block)
             self._state = self._state._replace(tables=tables)
             self.alloc.unref(dead)
+
+    @property
+    def use_kernels(self) -> bool:
+        """True when paged attention runs the Pallas kernel (compiled or
+        interpreted), False on the jnp gather path."""
+        return self.kernels is not None
 
     @property
     def pending(self) -> bool:

@@ -88,12 +88,13 @@ def build_spec_chunk(cfg: ModelConfig, draft_cfg: ModelConfig,
     then run two-span attention — draft propose and target verify both
     see exact window + sketched tail.
 
-    ``kernels`` (static) routes draft micro-steps and the target verify
-    through the flash-decode paged Pallas kernels
-    (kernels/paged_attention.py); the kernel's verify rows are bitwise
-    the kernel's single-token decode rows, so greedy spec identity holds
-    on either implementation — but only when plain and speculative
-    engines resolve the SAME choice, which the scheduler guarantees.
+    ``kernels`` (static, a resolved ``kops.kernel_mode``) routes draft
+    micro-steps and the target verify through the flash-decode paged
+    Pallas kernels (kernels/paged_attention.py); the kernel's verify rows
+    are bitwise the kernel's single-token decode rows, so greedy spec
+    identity holds on either implementation — but only when plain and
+    speculative engines resolve the SAME choice, which the scheduler
+    guarantees.
     """
     K = spec_max
     V = cfg.vocab_size

@@ -53,7 +53,7 @@ def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     compress = (cfg.sketch.grad_compression if grad_compression is None
                 else grad_compression)
     key = jax.random.PRNGKey(seed)
-    params = M.init_params(key, cfg)
+    params = M.init_params_jit(key, cfg)
     opt_init, opt_update = make_optimizer(cfg, lr=lr)
     opt = opt_init(params)
     if cfg.sketch.opt_state_ratio > 0:

@@ -1,49 +1,11 @@
 """Core sketching correctness: structural identities (exact), statistical
 properties (unbiasedness, variance ordering FCS <= TS, Cor.1 scaling), and
 hypothesis property tests (linearity/scaling invariants)."""
-import itertools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    # Fallback shim: hypothesis isn't installed in this container.  Run
-    # each @given test over a small deterministic grid drawn from the
-    # strategy bounds instead of failing collection.
-    class _Strategy:
-        def __init__(self, samples):
-            self.samples = list(samples)
-
-    class st:  # noqa: N801 - mimics the hypothesis module name
-        @staticmethod
-        def floats(lo, hi):
-            return _Strategy([lo, hi, 0.5 * (lo + hi), 0.25 * lo + 0.75 * hi])
-
-        @staticmethod
-        def integers(lo, hi):
-            return _Strategy([lo, hi, (lo + hi) // 2, lo + 12345 % max(hi - lo, 1)])
-
-        @staticmethod
-        def sampled_from(seq):
-            return _Strategy(seq)
-
-    def settings(**_kw):
-        return lambda fn: fn
-
-    def given(**strategies):
-        names = list(strategies)
-
-        def deco(fn):
-            def wrapper(self, *a):
-                grid = itertools.product(*(strategies[n].samples
-                                           for n in names))
-                for combo in itertools.islice(grid, 20):
-                    fn(self, *a, **dict(zip(names, combo)))
-            return wrapper
-        return deco
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (
     fcs_cp, fcs_general, fcs_kron_compress, fcs_kron_decompress,

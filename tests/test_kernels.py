@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp
-oracles (interpret mode executes the kernel body on CPU).
+oracles.  Every kernel defaults to its compiled TPU form, so each call
+here asks for interpret mode, which executes the kernel body on CPU.
 
 The CI kernel-oracle matrix job selects one kernel family per matrix
 entry with ``pytest tests/test_kernels.py -k <family>`` — every
@@ -14,8 +15,8 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.count_sketch import count_sketch
-from repro.kernels.ops import (count_sketch_op, paged_attention_op,
-                               unsketch_op)
+from repro.kernels.ops import (count_sketch_op, kernel_mode,
+                               paged_attention_op, unsketch_op)
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.unsketch import unsketch
 
@@ -40,7 +41,7 @@ def test_count_sketch_matches_ref_f32(shape, blocks):
     B, I, J = shape
     bB, bI, bJ = blocks
     x, h, s = _inputs(B, I, J, jnp.float32)
-    out = count_sketch(x, h, s, J, bB=bB, bI=bI, bJ=bJ)
+    out = count_sketch(x, h, s, J, bB=bB, bI=bI, bJ=bJ, interpret=True)
     np.testing.assert_allclose(out, ref.count_sketch_ref(x, h, s, J),
                                rtol=2e-5, atol=2e-5)
 
@@ -50,7 +51,7 @@ def test_unsketch_matches_ref_f32(shape):
     B, I, J = shape
     _, h, s = _inputs(B, I, J, jnp.float32)
     y = jax.random.normal(jax.random.PRNGKey(9), (B, J))
-    out = unsketch(y, h, s, bB=2, bI=128, bJ=128)
+    out = unsketch(y, h, s, bB=2, bI=128, bJ=128, interpret=True)
     np.testing.assert_allclose(out, ref.unsketch_ref(y, h, s),
                                rtol=2e-5, atol=2e-5)
 
@@ -59,7 +60,7 @@ def test_unsketch_matches_ref_f32(shape):
                                        (jnp.float32, 2e-5)])
 def test_count_sketch_dtypes(dtype, tol):
     x, h, s = _inputs(4, 512, 128, dtype)
-    out = count_sketch(x, h, s, 128)
+    out = count_sketch(x, h, s, 128, interpret=True)
     refv = ref.count_sketch_ref(x.astype(jnp.float32), h, s, 128)
     np.testing.assert_allclose(out.astype(jnp.float32), refv,
                                rtol=tol, atol=tol)
@@ -70,13 +71,23 @@ def test_roundtrip_unbiased_entries():
     contains its own value plus zero-mean collision noise)."""
     B, I, J = 1, 256, 4096
     x, h, s = _inputs(B, I, J, jnp.float32, seed=3)
-    y = count_sketch(x, h, s, J)
-    xhat = unsketch(y, h, s)
+    y = count_sketch(x, h, s, J, interpret=True)
+    xhat = unsketch(y, h, s, interpret=True)
     err = jnp.abs(xhat - x)
     # J >> I: expected collision-free fraction ~ (1 - 1/J)^(I-1) ~ 94%
     frac_exact = float(jnp.mean(err < 1e-4))
     assert frac_exact > 0.85, frac_exact
     assert float(jnp.median(err)) < 1e-5
+
+
+def test_kernel_mode_resolution():
+    """The one compiled-or-interpreted decision: a TPU runs the kernel
+    compiled; elsewhere the kernel runs only when asked for, and then
+    interpreted."""
+    on_tpu = jax.default_backend() == "tpu"
+    assert kernel_mode(None) == ("compiled" if on_tpu else None)
+    assert kernel_mode(True) == ("compiled" if on_tpu else "interpret")
+    assert kernel_mode(False) is None
 
 
 def test_ops_dispatch():
@@ -106,7 +117,8 @@ def test_sketch_update_matches_ref():
     m_t = jnp.asarray(rng.randn(R, C).astype(np.float32))
     v_t = jnp.abs(jnp.asarray(rng.randn(R, C).astype(np.float32)))
     cm, cv = cached_coeffs(3, R), cached_coeffs(5, R)
-    got = sketch_update(g, m_t, v_t, cm, cv, b1=0.9, b2=0.95)
+    got = sketch_update(g, m_t, v_t, cm, cv, b1=0.9, b2=0.95,
+                        interpret=True)
     want = ref.sketch_update_ref(g, m_t, v_t, cm, cv, 0.9, 0.95)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -123,7 +135,7 @@ def test_kv_tail_fold_matches_ref():
     rows = jnp.asarray(rng.randn(N, D).astype(np.float32))
     pos = jnp.asarray(rng.randint(0, T, (N,)).astype(np.int32))
     tail = jnp.asarray(rng.randn(Z, C, D).astype(np.float32))
-    got = kk.tail_fold(rows, pos, tail, coeffs, bN=32, bC=32)
+    got = kk.tail_fold(rows, pos, tail, coeffs, bN=32, bC=32, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref.kv_tail_fold_ref(rows, pos, tail,
                                                          coeffs)),
@@ -139,7 +151,8 @@ def test_kv_tail_scores_matches_ref():
     coeffs = cached_coeffs(11, Z)
     q = jnp.asarray(rng.randn(N, D).astype(np.float32))
     tail_k = jnp.asarray(rng.randn(Z, C, D).astype(np.float32))
-    got = kk.tail_scores(q, tail_k, coeffs, T=T, bN=16, bT=64)
+    got = kk.tail_scores(q, tail_k, coeffs, T=T, bN=16, bT=64,
+                         interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref.kv_tail_scores_ref(q, tail_k,
                                                            coeffs, T)),
@@ -183,7 +196,7 @@ def test_paged_attention_matches_ref(shape):
     """Interpret-mode kernel vs the jnp online-softmax oracle: the block
     loop is op-for-op identical, so the statistics agree to ~bitwise
     (asserted at rtol 1e-5, well below the acceptance bar)."""
-    got = paged_attention(*_paged_inputs(*shape))
+    got = paged_attention(*_paged_inputs(*shape), interpret=True)
     want = ref.paged_attention_ref(*_paged_inputs(*shape))
     for name, a, b in zip("m l acc".split(), got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -198,7 +211,8 @@ def test_paged_attention_ragged_lengths_dense_oracle():
     B, Sq, K, R, hd, NB, bs, nb = 2, 4, 2, 2, 16, 10, 4, 5
     q, kp, vp, tables, start, fb = _paged_inputs(B, Sq, K, R, hd, NB, bs,
                                                  nb, seed=5)
-    m, l, acc = paged_attention(q, kp, vp, tables, start, fb)
+    m, l, acc = paged_attention(q, kp, vp, tables, start, fb,
+                                interpret=True)
     out = np.asarray(acc / jnp.maximum(l, 1e-30)[..., None])
     S = nb * bs
     kt = np.asarray(jnp.take(kp, tables, axis=0, mode="fill",
@@ -231,7 +245,7 @@ def test_paged_attention_invalid_rows_drop():
     B, Sq, K, R, hd, NB, bs, nb = 2, 2, 2, 2, 16, 12, 4, 3
     q, kp, vp, tables, start, fb = _paged_inputs(B, Sq, K, R, hd, NB, bs,
                                                  nb, seed=7)
-    ref_out = paged_attention(q, kp, vp, tables, start, fb)
+    ref_out = paged_attention(q, kp, vp, tables, start, fb, interpret=True)
     used = set(np.asarray(tables)[np.asarray(tables) < NB].tolist())
     unused = [j for j in range(NB) if j not in used]
     assert unused, "fixture must leave unreferenced pool blocks"
@@ -239,7 +253,8 @@ def test_paged_attention_invalid_rows_drop():
     kp2[unused] = 1e4
     vp2[unused] = -1e4
     got = paged_attention(q, jnp.asarray(kp2, kp.dtype),
-                          jnp.asarray(vp2, vp.dtype), tables, start, fb)
+                          jnp.asarray(vp2, vp.dtype), tables, start, fb,
+                          interpret=True)
     for a, b in zip(ref_out, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -251,10 +266,11 @@ def test_paged_attention_decode_rows_bitwise_match_verify():
     B, Sq, K, R, hd, NB, bs, nb = 2, 4, 2, 2, 16, 10, 4, 5
     q, kp, vp, tables, start, fb = _paged_inputs(B, Sq, K, R, hd, NB, bs,
                                                  nb, seed=9)
-    mv, lv, av = paged_attention(q, kp, vp, tables, start, fb)
+    mv, lv, av = paged_attention(q, kp, vp, tables, start, fb,
+                                 interpret=True)
     for i in range(Sq):
         m1, l1, a1 = paged_attention(q[:, i:i + 1], kp, vp, tables,
-                                     start + i, fb)
+                                     start + i, fb, interpret=True)
         np.testing.assert_array_equal(np.asarray(m1[..., 0]),
                                       np.asarray(mv[..., i]))
         np.testing.assert_array_equal(np.asarray(l1[..., 0]),
@@ -266,8 +282,8 @@ def test_paged_attention_decode_rows_bitwise_match_verify():
 def test_paged_attention_ops_dispatch():
     shape = PAGED_SHAPES[1]
     args = _paged_inputs(*shape, seed=11)
-    got = paged_attention_op(*args, use_pallas=True)
-    want = paged_attention_op(*args, use_pallas=False)
+    got = paged_attention_op(*args, kernels="interpret")
+    want = paged_attention_op(*args, kernels=None)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
@@ -280,7 +296,8 @@ def test_paged_attention_traced_start():
     shape = PAGED_SHAPES[2]
     q, kp, vp, tables, start, fb = _paged_inputs(*shape, seed=13)
 
-    calls = jax.jit(lambda s: paged_attention(q, kp, vp, tables, s, fb))
+    calls = jax.jit(lambda s: paged_attention(q, kp, vp, tables, s, fb,
+                                              interpret=True))
     a = calls(start)
     b = calls(start + 4)
     assert calls._cache_size() == 1

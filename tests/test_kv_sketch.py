@@ -302,7 +302,7 @@ def test_tail_fold_kernel_matches_oracle():
     rows = jnp.asarray(rng.randn(N, D).astype(np.float32))
     pos = jnp.asarray(rng.randint(0, T, (N,)).astype(np.int32))
     tail = jnp.asarray(rng.randn(Z, C, D).astype(np.float32))
-    got = kk.tail_fold(rows, pos, tail, coeffs, bN=64, bC=32)
+    got = kk.tail_fold(rows, pos, tail, coeffs, bN=64, bC=32, interpret=True)
     want = ref.kv_tail_fold_ref(rows, pos, tail, coeffs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4)
@@ -318,7 +318,8 @@ def test_tail_scores_kernel_matches_oracle():
     coeffs = cached_coeffs(11, Z)
     q = jnp.asarray(rng.randn(N, D).astype(np.float32))
     tail_k = jnp.asarray(rng.randn(Z, C, D).astype(np.float32))
-    got = kk.tail_scores(q, tail_k, coeffs, T=T, bN=16, bT=64)
+    got = kk.tail_scores(q, tail_k, coeffs, T=T, bN=16, bT=64,
+                         interpret=True)
     want = ref.kv_tail_scores_ref(q, tail_k, coeffs, T)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4)

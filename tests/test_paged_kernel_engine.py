@@ -11,6 +11,8 @@ the engine-level token assertions are the structural contracts that ARE
 bitwise on the kernel path: speculative == plain, sketched anchor ==
 sketch-free, run-to-run determinism, one decode compilation."""
 import dataclasses
+import importlib.util
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +55,8 @@ def _run(cfg, params, serve, reqs):
 
 def test_kernel_logits_match_jnp(gemma):
     """decode_step and verify_step on the SAME prefilled paged cache:
-    kernels=True logits agree with kernels=False logits to bf16-level
-    tolerance across every slot and verify row."""
+    interpret-mode kernel logits agree with jnp gather-path logits to
+    bf16-level tolerance across every slot and verify row."""
     cfg, params = gemma
     B, bs, nper = 2, 16, 4
     tables = jnp.arange(B * nper, dtype=jnp.int32).reshape(B, nper)
@@ -67,22 +69,63 @@ def test_kernel_logits_match_jnp(gemma):
         for s in (0, 16):
             cache = tf.prefill_chunk(
                 params, cache, jnp.asarray(toks[:, s:s + 16]),
-                tables[b], jnp.int32(s), cfg, kernels=False)
+                tables[b], jnp.int32(s), cfg)
     cur = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, 1)), jnp.int32)
     pos = jnp.asarray(lens, jnp.int32)
     lg_k, _ = tf.decode_step(params, dict(cache), cur, pos, cfg,
-                             tables=tables, kernels=True)
+                             tables=tables, kernels="interpret")
     lg_j, _ = tf.decode_step(params, dict(cache), cur, pos, cfg,
-                             tables=tables, kernels=False)
+                             tables=tables, kernels=None)
     np.testing.assert_allclose(np.asarray(lg_k), np.asarray(lg_j),
                                rtol=5e-2, atol=5e-2)
     vt = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, 3)), jnp.int32)
     vg_k, _ = tf.verify_step(params, dict(cache), vt, pos, cfg,
-                             tables=tables, kernels=True)
+                             tables=tables, kernels="interpret")
     vg_j, _ = tf.verify_step(params, dict(cache), vt, pos, cfg,
-                             tables=tables, kernels=False)
+                             tables=tables, kernels=None)
     np.testing.assert_allclose(np.asarray(vg_k), np.asarray(vg_j),
                                rtol=5e-2, atol=5e-2)
+
+
+def test_smoke_logit_check_flags_planted_fault():
+    """chip_smoke.py's kernel-vs-jnp logit check, on a GQA model with a
+    ragged batch: the intact kernel stays within every slot's bound and
+    flips no decided top-1, while the kernel with a planted fault (each
+    slot's first visible key dropped) breaks the bound on every slot."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", Path(__file__).resolve().parents[1] / "chip_smoke.py")
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    cfg = reduced_config("minitron-4b")
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    B, bs, nper = 4, 16, 4
+    tables = jnp.arange(B * nper, dtype=jnp.int32).reshape(B, nper)
+    cache = tf.init_paged_cache(cfg, B * nper, bs)
+    rng = np.random.RandomState(0)
+    lens = [9, 23, 32, 40]
+    for b, n in enumerate(lens):
+        toks = np.zeros((1, 48), np.int32)
+        toks[0, :n] = rng.randint(0, cfg.vocab_size, (n,))
+        for s in range(0, n, 16):
+            cache = tf.prefill_chunk(
+                params, cache, jnp.asarray(toks[:, s:s + 16]),
+                tables[b], jnp.int32(s), cfg)
+    cur = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, 1)), jnp.int32)
+    pos = jnp.asarray(lens, jnp.int32)
+    exact = np.zeros(B, bool)
+    lg_k, _ = tf.decode_step(params, dict(cache), cur, pos, cfg,
+                             tables=tables, kernels="interpret")
+    lg_j, _ = tf.decode_step(params, dict(cache), cur, pos, cfg,
+                             tables=tables, kernels=None)
+    rel, bound, _, flips, _ = smoke.logit_readings(lg_k, lg_j,
+                                                   cfg.vocab_size, exact)
+    assert (rel <= bound).all() and not flips.any(), rel
+    with smoke.first_key_dropped():
+        lg_f, _ = tf.decode_step(params, dict(cache), cur, pos, cfg,
+                                 tables=tables, kernels="interpret")
+    rel, bound, _, _, _ = smoke.logit_readings(lg_f, lg_j, cfg.vocab_size,
+                                               exact)
+    assert (rel > bound).all(), rel
 
 
 def test_kernel_engine_deterministic_and_compiles_once(gemma):
@@ -94,7 +137,7 @@ def test_kernel_engine_deterministic_and_compiles_once(gemma):
     lens = [5, 17, 9, 26]
     s, got = _run(cfg, params, _serve(cfg, paged_kernels=True),
                   _reqs(cfg, lens))
-    assert s.use_kernels
+    assert s.use_kernels and s.kernels == "interpret"
     assert sorted(got) == list(range(len(lens)))
     assert all(len(t) == 6 for t in got.values())
     assert s.decode_compilations == 1
@@ -148,12 +191,15 @@ def test_kernel_sketched_anchor_and_fold(gemma):
 
 
 def test_paged_kernels_resolution(gemma):
-    """paged_kernels=None auto-detects the backend exactly once at
-    construction (False on CPU), and the flag is ignored for engines
-    without a paged KV pool."""
+    """paged_kernels=None resolves exactly once at construction, through
+    kops.kernel_mode: the compiled kernel on TPU, the jnp path elsewhere
+    (no kernel on CPU), and the flag is ignored for engines without a
+    paged KV pool."""
     cfg, params = gemma
     s = SlotScheduler(cfg, params, serve=_serve(cfg))
-    assert s.use_kernels == (jax.default_backend() == "tpu")
+    on_tpu = jax.default_backend() == "tpu"
+    assert s.use_kernels == on_tpu
+    assert s.kernels == ("compiled" if on_tpu else None)
     xcfg = reduced_config("xlstm-1.3b")
     xp = M.init_params(jax.random.PRNGKey(0), xcfg)
     sx = SlotScheduler(

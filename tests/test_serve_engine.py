@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.registry import reduced_config
 from repro.models import layers as ly
@@ -318,7 +320,9 @@ def test_serve_state_pspecs(arch):
         assert specs.cache["kv"]["k"] == P(None, "model", None, None, None)
         assert specs.tables == P(None, None)
     else:
-        assert specs.cache["mlstm"]["C"][1] == b
+        # compare specs, not raw entries: PartitionSpec normalises a
+        # one-axis tuple entry to the bare axis name
+        assert P(specs.cache["mlstm"]["C"][1]) == P(b)
         assert specs.tables == P(b, None)
     assert specs.pos == P(b)
     assert specs.temp == P(b)
@@ -712,18 +716,8 @@ def _allocator_program(num_blocks: int, seed: int, steps: int) -> None:
     assert alloc.reserved == 0 and alloc.free_count == num_blocks
 
 
-try:
-    from hypothesis import given, settings, strategies as st
-
-    @settings(max_examples=25, deadline=None)
-    @given(num_blocks=st.integers(1, 12), seed=st.integers(0, 1 << 16),
-           steps=st.integers(1, 120))
-    def test_block_allocator_fuzz(num_blocks, seed, steps):
-        _allocator_program(num_blocks, seed, steps)
-except ImportError:
-    # hypothesis isn't installed in this container: run the same property
-    # over a deterministic grid of random programs instead
-    @pytest.mark.parametrize("num_blocks,seed", [
-        (1, 0), (2, 1), (3, 2), (4, 3), (6, 4), (8, 5), (12, 6), (5, 7)])
-    def test_block_allocator_fuzz(num_blocks, seed):
-        _allocator_program(num_blocks, seed, steps=120)
+@settings(max_examples=25, deadline=None)
+@given(num_blocks=st.integers(1, 12), seed=st.integers(0, 1 << 16),
+       steps=st.integers(1, 120))
+def test_block_allocator_fuzz(num_blocks, seed, steps):
+    _allocator_program(num_blocks, seed, steps)
