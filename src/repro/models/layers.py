@@ -624,6 +624,7 @@ def _head_io(params: dict, x: jax.Array, cfg: ModelConfig):
     return x, head
 
 
+@jax.named_scope("lm_head")
 def logits_fn(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Final-hidden -> vocab logits (f32)."""
     x, head = _head_io(params, x, cfg)

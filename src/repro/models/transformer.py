@@ -111,38 +111,41 @@ def _dense_block(p_l, x, cfg: ModelConfig, positions, cache_l, index, mode,
     ``kernels``: paged modes — a resolved ``kops.kernel_mode``:
     "compiled" / "interpret" route attention through the flash-decode
     Pallas kernel, None through the jnp gather path."""
-    h = ly.rms_norm(x, p_l["norm1"], cfg.norm_eps)
-    new_cache = None
-    if mode == "decode":
-        a, new_cache = ly.decode_attention(p_l["attn"], h, cfg, cache_l,
-                                           index, tables=tables,
-                                           tail=tail_l, sketch=sketch,
-                                           kernels=kernels)
-    elif mode == "verify":
-        a, new_cache = ly.verify_attention(p_l["attn"], h, cfg, cache_l,
-                                           index, tables, tail=tail_l,
-                                           sketch=sketch,
-                                           kernels=kernels)
-    elif mode == "chunk":
-        a, new_cache = ly.chunk_attention(p_l["attn"], h, cfg, cache_l,
-                                          tables, index, tail=tail_l,
-                                          sketch=sketch,
-                                          kernels=kernels)
-    else:
-        a = ly.causal_attention(p_l["attn"], h, cfg, positions)
-        if mode == "prefill":
-            # re-derive roped k/v for the cache (cheap vs attention itself)
-            q, k, v = ly._project_qkv(p_l["attn"], h, cfg)
-            del q
-            k = ly.rope(k, positions, cfg.rope_theta)
-            new_cache = {"k": k, "v": v}
+    with jax.named_scope("attention"):
+        h = ly.rms_norm(x, p_l["norm1"], cfg.norm_eps)
+        new_cache = None
+        if mode == "decode":
+            a, new_cache = ly.decode_attention(p_l["attn"], h, cfg, cache_l,
+                                               index, tables=tables,
+                                               tail=tail_l, sketch=sketch,
+                                               kernels=kernels)
+        elif mode == "verify":
+            a, new_cache = ly.verify_attention(p_l["attn"], h, cfg, cache_l,
+                                               index, tables, tail=tail_l,
+                                               sketch=sketch,
+                                               kernels=kernels)
+        elif mode == "chunk":
+            a, new_cache = ly.chunk_attention(p_l["attn"], h, cfg, cache_l,
+                                              tables, index, tail=tail_l,
+                                              sketch=sketch,
+                                              kernels=kernels)
+        else:
+            a = ly.causal_attention(p_l["attn"], h, cfg, positions)
+            if mode == "prefill":
+                # re-derive roped k/v for the cache (cheap vs attention
+                # itself)
+                q, k, v = ly._project_qkv(p_l["attn"], h, cfg)
+                del q
+                k = ly.rope(k, positions, cfg.rope_theta)
+                new_cache = {"k": k, "v": v}
     x = x + a
-    h = ly.rms_norm(x, p_l["norm2"], cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.family == "moe":
-        f, aux = moe_mod.moe_ffn(p_l["moe"], h, cfg)
-    else:
-        f = ly.glu_ffn(p_l["ffn"], h, cfg.act)
+    with jax.named_scope("mlp"):
+        h = ly.rms_norm(x, p_l["norm2"], cfg.norm_eps)
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.family == "moe":
+            f, aux = moe_mod.moe_ffn(p_l["moe"], h, cfg)
+        else:
+            f = ly.glu_ffn(p_l["ffn"], h, cfg.act)
     return x + f, aux, new_cache
 
 
@@ -203,7 +206,8 @@ def forward(params: Params, x: jax.Array, cfg: ModelConfig,
                                            cache, index)
     else:
         raise ValueError(fam)
-    y = ly.rms_norm(y, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        y = ly.rms_norm(y, params["final_norm"], cfg.norm_eps)
     return y, aux, new_cache
 
 

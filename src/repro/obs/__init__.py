@@ -4,21 +4,23 @@ sketch-fidelity telemetry.
 Layering::
 
     trace.py    Chrome trace-event recorder (sampled, bounded, host-only)
+                and ``span``, the serve path's one phase timer
     metrics.py  counter / gauge / log-bucket-histogram registry with
                 windowed (interval-delta) snapshots
     export.py   trace JSON + metrics JSONL + Prometheus text sinks
     __init__    ServeObserver — the one object the serve layer talks to
 
-The serve layer (``repro.serve``) never imports trace/metrics/export
-directly: the scheduler and the async front-end hold an optional
-``obs`` attribute (a ``ServeObserver`` or ``None``) and guard every
-hook with ``if self.obs is not None`` — observability off means zero
-extra work beyond one attribute check per site.  Every hook consumes
-host-side values the pump already holds (mirrors, counters, wall-clock
-durations), so enabling observability adds ZERO device syncs to the
-hot path; the only exception is the opt-in sketch-fidelity probe,
-which runs at the existing per-round ``collect()`` sync point and only
-at its configured cadence.
+The serve layer (``repro.serve``) imports only ``span`` from here: the
+scheduler and the async front-end hold an optional ``obs`` attribute
+(a ``ServeObserver`` or ``None``) and guard every hook with ``if
+self.obs is not None`` — observability off means zero extra work
+beyond one attribute check per site, and a ``span`` with no profiler
+session on costs one check more.  Every hook consumes host-side values
+the pump already holds (mirrors, counters, wall-clock durations), so
+enabling observability adds ZERO device syncs to the hot path; the only
+exception is the opt-in sketch-fidelity probe, which runs at the
+existing per-round ``collect()`` sync point and only at its configured
+cadence.
 """
 from __future__ import annotations
 
@@ -29,12 +31,12 @@ from repro.obs.export import (MetricsJsonlWriter, prometheus_text,
                               write_trace)
 from repro.obs.metrics import (Counter, Gauge, Histogram,
                                MetricsRegistry)
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "MetricsJsonlWriter", "ServeObserver", "Tracer",
-    "prometheus_text", "write_trace",
+    "prometheus_text", "span", "write_trace",
 ]
 
 _MAX_WINDOWS = 512          # retained in-memory snapshots (tests, CLI)
@@ -184,23 +186,20 @@ class ServeObserver:
 
     def pump_span(self, name: str, t0_s: float, dur_s: float,
                   args: Optional[dict] = None) -> None:
-        """One host-side pump phase ("dispatch" host time, "collect"
-        block time) as an "X" span; also feeds the phase histogram."""
+        """One host-side phase timed by ``repro.obs.span`` ("dispatch",
+        "collect" on the scheduler's worker thread, "prefill_chunk",
+        "admit") as an "X" span; also feeds the phase histogram
+        ``pump.<name>_s``."""
         self.registry.hist(f"pump.{name}_s").observe(dur_s)
         if self.tracer is not None:
             self.tracer.complete(name, t0_s * 1e6, dur_s * 1e6, args)
 
     def prefill_span(self, slot: int, off: int, rows: int,
                      dur_s: float) -> None:
-        """Host dispatch time of one chunked-prefill step (the device
-        work is async; this is the pump-side cost)."""
+        """One chunked-prefill step dispatched (``dur_s`` host seconds;
+        the device work is async).  Its "X" span comes through
+        ``pump_span`` as "prefill_chunk"; here it is counted."""
         self.registry.counter("serve.prefill_chunks").inc()
-        if self.tracer is not None:
-            t1 = time.perf_counter()
-            self.tracer.complete("prefill_chunk", (t1 - dur_s) * 1e6,
-                                 dur_s * 1e6,
-                                 {"slot": int(slot), "off": int(off),
-                                  "rows": int(rows)})
 
     def fold(self, slot: int, rows: int) -> None:
         """``rows`` KV rows folded from a slot's exact window into its

@@ -29,15 +29,26 @@ Event vocabulary (Chrome trace-event phases):
            nested ``active`` span per residency (admission -> retire /
            preempt; a preempted request opens a fresh ``active`` span
            when it is re-admitted);
-  "X"      complete spans for pump phases (dispatch host time, collect
-           block time, per-chunk prefill dispatch) on tid 1;
+  "X"      complete spans for pump phases (dispatch host time, the
+           scheduler's collect on its worker thread, per-chunk prefill
+           dispatch, one admission attempt) on tid 1;
   "i"      instants for point events (folds, preemptions, expiries);
   "C"      counter tracks (queue depth / active slots per round).
+
+``span`` is the one way the serve path times a phase: it writes the
+interval into JAX's profiler trace as ``serve.<name>`` (a TraceMe, on
+the same clock as the device's ops when a profiler session is on) and,
+where an observer is given, into this Chrome trace through the
+observer's ``pump_span`` hook.  The serve path gives one to its four
+pump phases (``dispatch``, ``collect``, ``prefill_chunk``, ``admit``)
+and to no other span, so the bounded trace keeps its room for requests.
 """
 from __future__ import annotations
 
 import time
 from typing import Any, Dict, List, Optional
+
+import jax
 
 # pid/tid layout: one fake process; tid 0 carries request spans and
 # counters, tid 1 carries pump-phase spans, so Perfetto renders the
@@ -49,6 +60,57 @@ _TID_PUMP = 1
 # Knuth multiplicative hash: deterministic rid -> [0, 1) sampling that
 # needs no RNG state and never re-decides for the same request
 _HASH_MULT = 2654435761
+
+
+_Annotation = jax.profiler.TraceAnnotation
+
+
+class _Span:
+    """Context manager behind ``span``; ``dur_s`` holds the interval's
+    host seconds after exit where an observer is attached (else None)."""
+
+    __slots__ = ("_tm", "_name", "_obs", "_args", "_t0", "dur_s")
+
+    def __init__(self, name: str, obs: Any, args: Dict[str, Any]):
+        self._name = name
+        self._obs = obs
+        self._args = args
+        self.dur_s: Optional[float] = None
+
+    def __enter__(self) -> "_Span":
+        # the TraceMe is built only while a profiler session records
+        if _Annotation.is_enabled():
+            self._tm = _Annotation(f"serve.{self._name}", **self._args)
+            self._tm.__enter__()
+        else:
+            self._tm = None
+        if self._obs is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **args: Any) -> None:
+        """Arguments known only once the work is done (``ok`` of an
+        admission attempt)."""
+        if self._tm is not None:
+            self._tm.set_metadata(**args)
+        self._args.update(args)
+
+    def __exit__(self, *exc) -> None:
+        if self._obs is not None:
+            self.dur_s = time.perf_counter() - self._t0
+            self._obs.pump_span(self._name, self._t0, self.dur_s,
+                                self._args or None)
+        if self._tm is not None:
+            self._tm.__exit__(*exc)
+
+
+def span(name: str, obs: Any = None, **args: Any) -> _Span:
+    """Time one phase of the serve path as ``serve.<name>`` in JAX's
+    profiler trace (``args`` become the event's metadata); with no
+    profiler session on, that costs one check.  Where ``obs`` (a
+    ``ServeObserver`` or any object with its ``pump_span`` hook) is
+    given, the same interval is also reported there, under ``name``."""
+    return _Span(name, obs, args)
 
 
 class Tracer:

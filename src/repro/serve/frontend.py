@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.models.draft import Draft
+from repro.obs import span
 from repro.serve.scheduler import (Completion, EngineStats, Request,
                                    SlotScheduler)
 
@@ -237,42 +238,37 @@ class AsyncServeEngine:
 
     async def _pump(self) -> None:
         sched = self._sched
-        obs = self.obs
         while True:
-            # pump boundary: no chunk in flight — evictions are safe
-            self._apply_cancels()
-            for c in sched.expire_deadlines():
-                self._finish(c)
-            self._notify_space()
+            with span("boundary"):
+                # pump boundary: no chunk in flight — evictions are safe
+                self._apply_cancels()
+                for c in sched.expire_deadlines():
+                    self._finish(c)
+                self._notify_space()
             sched.admit_pending()
-            t0 = time.perf_counter()
             if not sched.dispatch():
                 if not sched.pending:
                     return              # drained; next submit restarts
-                await asyncio.sleep(0)  # transient: let submitters run
+                with span("yield"):
+                    await asyncio.sleep(0)  # transient: let submitters run
                 continue
-            if obs is not None:
-                # host time spent launching the chunk (jax dispatch +
-                # fold planning) — the device work is still in flight
-                obs.pump_span("dispatch", t0, time.perf_counter() - t0)
             # overlap window: the chunk is crunching on-device; yield so
             # fresh submissions land, then run THEIR admission/prefill
             # host work now instead of serializing after collect
-            await asyncio.sleep(0)
+            with span("yield"):
+                await asyncio.sleep(0)
             sched.admit_pending()
-            t0 = time.perf_counter()
             done = await asyncio.to_thread(sched.collect)
-            if obs is not None:
-                # wall time blocked on the chunk's one device sync
-                obs.pump_span("collect", t0, time.perf_counter() - t0)
-            self._deliver_progress()
-            for c in done:
-                self._finish(c)
-            self._notify_space()
+            with span("deliver"):
+                self._deliver_progress()
+                for c in done:
+                    self._finish(c)
+                self._notify_space()
             # let consumers react to the tokens just delivered BEFORE
             # the next boundary, so a cancel() they issue now applies
             # ahead of the next dispatch instead of one chunk later
-            await asyncio.sleep(0)
+            with span("yield"):
+                await asyncio.sleep(0)
 
     def _apply_cancels(self) -> None:
         while self._cancel_rids:

@@ -119,6 +119,7 @@ def tail_state_bytes(tail: Any) -> int:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("fcs_fold")
 def fold_pool(pool: Dict[str, jax.Array], tail: Dict[str, jax.Array],
               tables: jax.Array, fold_from: jax.Array, fold_len: jax.Array,
               coeffs: jax.Array, fold_cap: int) -> Dict[str, jax.Array]:
@@ -166,6 +167,7 @@ def fold_pool(pool: Dict[str, jax.Array], tail: Dict[str, jax.Array],
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("fcs_tail")
 def tail_attend(q: jax.Array, tail_k: jax.Array, tail_v: jax.Array,
                 onehot: jax.Array, fold_base: jax.Array, scale: float
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:

@@ -40,6 +40,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from repro.configs.base import ServeConfig
+from repro.obs import span
 from repro.sketch import csvec
 
 # count-min key domain: prefix hashes land in [0, CM_DOMAIN)
@@ -161,9 +162,14 @@ class SketchPrefixCache:
         keys = np.array(
             [prefix_key(tokens[:m * block]) % CM_DOMAIN
              for m in range(1, n_blocks + 1)], np.int32)
-        self._cm = csvec.accumulate_coords(
-            self._cm, keys, np.ones(len(keys), np.float32))
-        counts = np.asarray(csvec.query(self._cm, keys))
+        # about 66 single-op programs (hashing, accumulate, query), each
+        # dispatched on its own: on a v5e the host's dispatch takes the
+        # time, with the device idle, not the read-back; behind programs
+        # still in flight the dispatches wait too (32 in flight at most)
+        with span("prefix_count"):
+            self._cm = csvec.accumulate_coords(
+                self._cm, keys, np.ones(len(keys), np.float32))
+            counts = np.asarray(csvec.query(self._cm, keys))
         self._observed += 1
         if self._observed % self.cfg.cm_decay_every == 0:
             self._cm = csvec.decay(self._cm, self.cfg.cm_decay)

@@ -123,6 +123,7 @@ from repro.configs.base import ModelConfig, ServeConfig
 from repro.kernels import ops as kops
 from repro.models import transformer as tf
 from repro.models.draft import Draft, make_draft
+from repro.obs import span
 from repro.serve import kv_sketch as kvs
 from repro.serve.prefix_cache import SketchPrefixCache
 from repro.serve.speculative import build_spec_chunk
@@ -131,6 +132,17 @@ from repro.serve.speculative import round_accounting as \
 
 KV_FAMILIES = ("dense", "moe", "audio", "vlm")
 RECURRENT_FAMILIES = ("ssm", "hybrid")
+
+
+def _named(name: str, fn):
+    """``fn`` under ``name``.  JAX names a jitted program after its
+    function (``jit_<name>`` in a profiler trace); a ``functools.partial``
+    has no name of its own and would show up as ``jit__unknown``."""
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 @dataclass
@@ -608,25 +620,29 @@ class SlotScheduler:
                 self._spread_fn = jax.jit(kvs.tail_row_spread)
             else:
                 self._prefill_chunk = jax.jit(
-                    functools.partial(tf.prefill_chunk, cfg=cfg,
-                                      kernels=self.kernels),
+                    _named("prefill_chunk", functools.partial(
+                        tf.prefill_chunk, cfg=cfg, kernels=self.kernels)),
                     donate_argnums=(1,))
                 if self.draft is not None:
                     self._draft_prefill_chunk = jax.jit(
-                        functools.partial(tf.prefill_chunk,
-                                          cfg=self.draft.cfg,
-                                          kernels=self.kernels),
+                        _named("draft_prefill_chunk", functools.partial(
+                            tf.prefill_chunk, cfg=self.draft.cfg,
+                            kernels=self.kernels)),
                         donate_argnums=(1,))
+
             # copy-on-write block fork: copy one physical block's rows
             # (target AND draft pools) to a fresh block, device-side
-            self._copy_block = jax.jit(
-                lambda c, src, dst: jax.tree.map(
-                    lambda a: a.at[:, dst].set(a[:, src]), c),
-                donate_argnums=(0,))
+            def copy_block(c, src, dst):
+                return jax.tree.map(
+                    lambda a: a.at[:, dst].set(a[:, src]), c)
+
+            self._copy_block = jax.jit(copy_block, donate_argnums=(0,))
         else:
-            self._insert_fn = jax.jit(self._insert_state,
-                                      donate_argnums=(0,))
-            self._prefill = jax.jit(functools.partial(tf.prefill, cfg=cfg))
+            self._insert_fn = jax.jit(
+                _named("insert_slot_state", self._insert_state),
+                donate_argnums=(0,))
+            self._prefill = jax.jit(_named(
+                "recurrent_prefill", functools.partial(tf.prefill, cfg=cfg)))
             # slot "reset" block: zero state inserted before (or instead
             # of, for 1-token prompts) the prefilled state
             self._zero_block = tf.init_cache(cfg, 1, sv.max_seq)
@@ -647,6 +663,7 @@ class SlotScheduler:
 
     @staticmethod
     def _make_sampler():
+        @jax.named_scope("lm_head")
         def sample(key, lg, temp, top_k):
             """Per-slot next token: greedy when temp == 0, else top-k
             filtered temperature sampling with the slot's own key.  The
@@ -793,8 +810,8 @@ class SlotScheduler:
         onehot = self.tail_onehot
         kernels = self.kernels
 
-        def spc(params, pool, tail_full, tok, table, start, slot,
-                fold_base):
+        def sketched_prefill_chunk(params, pool, tail_full, tok, table,
+                                   start, slot, fold_base):
             tail = jax.tree.map(
                 lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
                 tail_full)
@@ -804,7 +821,10 @@ class SlotScheduler:
                                   kernels=kernels)
             return nc["kv"]
 
-        return jax.jit(spc, donate_argnums=(1,))
+        name = ("draft_prefill_chunk" if is_draft
+                else "sketched_prefill_chunk")
+        return jax.jit(_named(name, sketched_prefill_chunk),
+                       donate_argnums=(1,))
 
     def _make_fold(self):
         """Jitted out-of-chunk fold (prefill fold-through): fold the next
@@ -840,7 +860,7 @@ class SlotScheduler:
     def _make_zero_tail(self):
         """Jitted per-slot tail reset (slot admission): a new occupant
         must never attend the previous request's folded content."""
-        def zt(cache, slot):
+        def zero_tail(cache, slot):
             z = lambda t: jax.tree.map(
                 lambda a: a.at[:, slot].set(0.0), t)
             out = {**cache, "tail": z(cache["tail"])}
@@ -849,7 +869,7 @@ class SlotScheduler:
                                 "tail": z(cache["draft"]["tail"])}
             return out
 
-        return zt
+        return zero_tail
 
     @staticmethod
     def _insert_state(cache, block, slot):
@@ -982,12 +1002,12 @@ class SlotScheduler:
             seg = prompt[off:off + bucket]
             tok = np.zeros((1, bucket), np.int32)
             tok[0, :len(seg)] = seg
-            t0 = time.perf_counter()
-            cache = self._prefill_one(cache, jnp.asarray(tok), table, off,
-                                      slot, 0)
+            with span("prefill_chunk", self.obs, slot=slot, off=off,
+                      rows=len(seg)) as sp:
+                cache = self._prefill_one(cache, jnp.asarray(tok), table,
+                                          off, slot, 0)
             if self.obs is not None:
-                self.obs.prefill_span(slot, off, len(seg),
-                                      time.perf_counter() - t0)
+                self.obs.prefill_span(slot, off, len(seg), sp.dur_s)
             off += bucket
         return cache
 
@@ -1029,16 +1049,16 @@ class SlotScheduler:
                 slot_ids.extend(ids)
             tok = np.zeros((1, bucket), np.int32)
             tok[0, :len(seg)] = seg
-            t0 = time.perf_counter()
             # jnp.array copies ``row``: the loop rewrites it in place
             # while the dispatched chunk may still read it, and on the CPU
             # jnp.asarray of an aligned numpy array aliases its memory
-            cache = self._prefill_one(cache, jnp.asarray(tok),
-                                      jnp.array(row), off, slot,
-                                      fold_base)
+            with span("prefill_chunk", self.obs, slot=slot, off=off,
+                      rows=len(seg)) as sp:
+                cache = self._prefill_one(cache, jnp.asarray(tok),
+                                          jnp.array(row), off, slot,
+                                          fold_base)
             if self.obs is not None:
-                self.obs.prefill_span(slot, off, len(seg),
-                                      time.perf_counter() - t0)
+                self.obs.prefill_span(slot, off, len(seg), sp.dur_s)
             # fold whole blocks that aged past the window ([0, end) keeps
             # >= W exact rows; the decode resume row S-1 always stays
             # exact because fold_base <= S - W <= S - 1)
@@ -1117,21 +1137,22 @@ class SlotScheduler:
         if self.is_kv:
             bs = self.block_size
             use_sketch = self.sketch_on and req.kv_sketch is not False
-            if req.rid not in self._admit_memo:
-                hit = self.prefix_cache.lookup(prompt)
+            first = req.rid not in self._admit_memo
+            with span("prefix_lookup"):
+                # a pool-pressure retry was counted on its first attempt:
+                # re-resolve the hit statelessly (the entry may have been
+                # evicted or admitted meanwhile, so stats reflect the
+                # first attempt while Completion.prefix_hit reflects how
+                # the request was actually served) and reuse the
+                # memoized admission decision
+                hit = (self.prefix_cache.lookup(prompt) if first
+                       else self.prefix_cache.peek(prompt))
+            if first:
                 # hits feed the admission path too: a hot prompt that
                 # keeps hitting a short cached prefix must still get its
                 # longer qualifying prefix admitted eventually
                 self._admit_memo[req.rid] = self.prefix_cache.observe(
                     prompt)
-            else:
-                # pool-pressure retry: the request was counted on its
-                # first attempt — re-resolve the hit statelessly (the
-                # entry may have been evicted or admitted meanwhile, so
-                # stats reflect the first attempt while Completion.
-                # prefix_hit reflects how the request was actually
-                # served) and reuse the memoized admission decision
-                hit = self.prefix_cache.peek(prompt)
             admit_plen = self._admit_memo[req.rid]
             shared: List[int] = []
             start_off = 0
@@ -1256,6 +1277,10 @@ class SlotScheduler:
             eff_spec = (self.serve.spec_k if req.spec_k is None
                         else int(req.spec_k))
             eff_spec = max(0, min(eff_spec, self.spec_max))
+        # each eager ``.at[].set`` here dispatches about five programs:
+        # behind the prefill chunks still in flight they reach the
+        # runtime's limit (32 programs in flight on a v5e), and the host
+        # then waits for the prefill to end before dispatching the rest
         st = st._replace(
             cache=cache,
             cur=st.cur.at[slot, 0].set(int(prompt[S - 1])),
@@ -1628,12 +1653,18 @@ class SlotScheduler:
             if slot is None:
                 slot = self._preempt_for(head) \
                     if self._inflight is None else None
-            if slot is None or not self._admit(slot, head):
-                if slot is not None and self.obs is not None:
+            if slot is None:
+                break                # full: wait
+            with span("admit", self.obs, rid=int(head.rid),
+                      slot=slot) as sp:
+                ok = self._admit(slot, head)
+                sp.set(ok=ok)
+            if not ok:
+                if self.obs is not None:
                     # a free slot existed but the pool couldn't serve
                     # the head request right now — a deferral stall
                     self.obs.admission_deferred(head.rid)
-                break                # full / pool pressure: wait
+                break                # pool pressure: wait
             self._queue.pop(0)
             admitted += 1
         return admitted
@@ -1647,30 +1678,31 @@ class SlotScheduler:
         assert self._inflight is None, "one decode chunk may be in flight"
         if not any(r is not None for r in self._slot_req):
             return False
-        fold_host = None
-        if self.sketch_on:
-            fold_host = self._plan_folds()
-        if self.spec_max > 0:
+        with span("dispatch", self.obs):
+            fold_host = None
             if self.sketch_on:
+                fold_host = self._plan_folds()
+            if self.spec_max > 0:
+                if self.sketch_on:
+                    self._state, toks, emits = self._chunk_fn(
+                        self.params, self.draft.params, self._state,
+                        jnp.asarray(fold_host))
+                else:
+                    self._state, toks, emits = self._chunk_fn(
+                        self.params, self.draft.params, self._state)
+            elif self.sketch_on:
                 self._state, toks, emits = self._chunk_fn(
-                    self.params, self.draft.params, self._state,
-                    jnp.asarray(fold_host))
+                    self.params, self._state, jnp.asarray(fold_host))
             else:
-                self._state, toks, emits = self._chunk_fn(
-                    self.params, self.draft.params, self._state)
-        elif self.sketch_on:
-            self._state, toks, emits = self._chunk_fn(
-                self.params, self._state, jnp.asarray(fold_host))
-        else:
-            self._state, toks, emits = self._chunk_fn(self.params,
-                                                      self._state)
-        if fold_host is not None:
-            # the fold's host half runs at dispatch time: the table
-            # sentinels enqueue AFTER the chunk in device-stream order,
-            # and any re-allocation's prefill writes enqueue later still
-            self._finish_folds(fold_host)
-        self.decode_steps += self.serve.decode_chunk
-        self._inflight = (toks, emits)
+                self._state, toks, emits = self._chunk_fn(self.params,
+                                                          self._state)
+            if fold_host is not None:
+                # the fold's host half runs at dispatch time: the table
+                # sentinels enqueue AFTER the chunk in device-stream order,
+                # and any re-allocation's prefill writes enqueue later still
+                self._finish_folds(fold_host)
+            self.decode_steps += self.serve.decode_chunk
+            self._inflight = (toks, emits)
         return True
 
     def collect(self) -> List[Completion]:
@@ -1682,44 +1714,47 @@ class SlotScheduler:
         was 0 when the chunk launched), so overlap never misattributes
         tokens."""
         assert self._inflight is not None, "collect() without dispatch()"
-        toks, emits = self._inflight
-        self._inflight = None
-        toks = np.asarray(toks)
-        emits = np.asarray(emits)
-        if toks.ndim == 2:               # plain chunk: one token per step
-            toks = toks[:, :, None]
-            emits = emits[:, :, None]
-        round_tokens = 0
-        for t in range(toks.shape[0]):
-            for s in range(toks.shape[1]):
-                if self._slot_req[s] is None:
-                    continue
-                e = int(emits[t, s].sum())
-                if e == 0:
-                    continue
-                self._slot_out[s].extend(
-                    int(x) for x in toks[t, s][emits[t, s]])
-                self._slot_pos[s] += e
-                round_tokens += e
-                # one verify round: slot proposed spec_k tokens and
-                # e - 1 of them survived verification
-                rr, pp, aa = spec_round_accounting(self._slot_spec[s], e)
-                if rr:
-                    self.spec_rounds += rr
-                    self.spec_proposed += pp
-                    self.spec_accepted += aa
-                    if self.obs is not None:
-                        self.obs.spec_round(self._slot_req[s].rid, pp, aa)
-        done = self._retire()
-        if self.obs is not None:
-            self._round_idx += 1
-            self.obs.chunk_collected(
-                round_tokens, len(self._queue),
-                sum(r is not None for r in self._slot_req))
-            if (self.sketch_on and self.obs.fidelity_every > 0
-                    and self._round_idx % self.obs.fidelity_every == 0):
-                self._probe_fidelity()
-            self.obs.maybe_flush(self.stats)
+        with span("collect", self.obs):
+            toks, emits = self._inflight
+            self._inflight = None
+            with span("collect.wait"):
+                toks = np.asarray(toks)
+                emits = np.asarray(emits)
+            if toks.ndim == 2:               # plain chunk: one token per step
+                toks = toks[:, :, None]
+                emits = emits[:, :, None]
+            round_tokens = 0
+            for t in range(toks.shape[0]):
+                for s in range(toks.shape[1]):
+                    if self._slot_req[s] is None:
+                        continue
+                    e = int(emits[t, s].sum())
+                    if e == 0:
+                        continue
+                    self._slot_out[s].extend(
+                        int(x) for x in toks[t, s][emits[t, s]])
+                    self._slot_pos[s] += e
+                    round_tokens += e
+                    # one verify round: slot proposed spec_k tokens and
+                    # e - 1 of them survived verification
+                    rr, pp, aa = spec_round_accounting(self._slot_spec[s], e)
+                    if rr:
+                        self.spec_rounds += rr
+                        self.spec_proposed += pp
+                        self.spec_accepted += aa
+                        if self.obs is not None:
+                            self.obs.spec_round(self._slot_req[s].rid, pp, aa)
+            with span("retire"):
+                done = self._retire()
+            if self.obs is not None:
+                self._round_idx += 1
+                self.obs.chunk_collected(
+                    round_tokens, len(self._queue),
+                    sum(r is not None for r in self._slot_req))
+                if (self.sketch_on and self.obs.fidelity_every > 0
+                        and self._round_idx % self.obs.fidelity_every == 0):
+                    self._probe_fidelity()
+                self.obs.maybe_flush(self.stats)
         return done
 
     def _probe_fidelity(self) -> None:
