@@ -148,8 +148,9 @@ class ServeConfig:
     ``paged_kernels``: attention implementation for the paged serve path
     (decode / speculative verify / chunked prefill).  None (default)
     picks the compiled flash-decode Pallas kernels
-    (``kernels/paged_attention.py`` — one pass over each slot's block
-    table, no dense gathered KV copy) on TPU, the jnp
+    (``kernels/paged_attention.py`` — one pass over the key tiles of
+    each slot's block table that hold its live keys, no dense gathered
+    KV copy) on TPU, the jnp
     gather-then-softmax oracle path elsewhere.  True forces the kernels
     (interpret mode off-TPU — the validation configuration), False
     forces the jnp path.  Resolved once at engine construction by

@@ -6,6 +6,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention import tile_blocks, tile_range
 from repro.sketch.csvec import CSVec, accumulate, query
 
 
@@ -56,51 +57,63 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         tables: jax.Array, start: jax.Array,
                         fold_base: jax.Array):
     """Oracle for kernels/paged_attention.py: the same online-softmax
-    block walk in plain jnp — a lax.scan over the slot's logical blocks,
-    fetching each physical block through the table (dead entries >= NB
-    clamp the fetch and mask the whole block), with op-for-op the
-    kernel's update equations and dtypes, so interpret mode reproduces it
-    bitwise.  Shapes/returns match ``paged_attention``: q (B,Sq,K,R,hd),
-    pools (NB,bs,K,hd), tables (B,nb) int32, start/fold_base (B,) ->
-    f32 (m, l, acc): (B,K,R,Sq) x2 and (B,K,R,Sq,hd)."""
+    tile walk in plain jnp — a lax.scan over the slot's key tiles of
+    ``tile_blocks`` table entries, fetching each tile's physical blocks
+    through the table (dead entries >= NB clamp the fetch and mask their
+    keys) and keeping a tile's update only where the kernel walks it (the
+    tile meets the slot's ``tile_range`` and holds a real entry), with
+    op-for-op the kernel's update equations and dtypes, so interpret mode
+    reproduces it bitwise.  Shapes/returns match ``paged_attention``: q
+    (B,Sq,K,R,hd), pools (NB,bs,K,hd), tables (B,nb) int32,
+    start/fold_base (B,) -> f32 (m, l, acc): (B,K,R,Sq) x2 and
+    (B,K,R,Sq,hd)."""
     B, Sq, K, R, hd = q.shape
     NB, bs = k_pool.shape[0], k_pool.shape[1]
     nb_slot = tables.shape[1]
     NQ = R * Sq
+    P = tile_blocks(bs, K, hd, nb_slot, NQ, k_pool.dtype.itemsize)
+    W = P * bs
+    T = -(-nb_slot // P)
     scale = 1.0 / math.sqrt(hd)
     qt = q.transpose(0, 2, 3, 1, 4).reshape(B, K, NQ, hd)
     st = start.astype(jnp.int32)
     fb = fold_base.astype(jnp.int32)
-    qi = jax.lax.broadcasted_iota(jnp.int32, (NQ, bs), 0) % Sq
+    lo, hi = tile_range(st, fb, Sq, W)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (NQ, W), 0) % Sq
 
-    def block(carry, j):
+    def tile(carry, t):
         m, l, acc = carry                # (B,K,NQ) x2, (B,K,NQ,hd)
-        entry = tables[:, j]             # (B,)
-        valid = entry < NB
-        kj = jnp.take(k_pool, jnp.minimum(entry, NB - 1), axis=0)
-        vj = jnp.take(v_pool, jnp.minimum(entry, NB - 1), axis=0)
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (NQ, bs), 1)
+        j = t * P + jnp.arange(P, dtype=jnp.int32)
+        entry = tables[:, jnp.minimum(j, nb_slot - 1)]           # (B, P)
+        valid = (j < nb_slot)[None] & (entry < NB)
+        walk = (t >= lo) & (t <= hi) & jnp.any(valid, axis=1)    # (B,)
+        blk = jnp.minimum(entry, NB - 1)
+        kj = jnp.take(k_pool, blk, axis=0).reshape(B, W, K, hd)
+        vj = jnp.take(v_pool, blk, axis=0).reshape(B, W, K, hd)
+        kpos = t * W + jax.lax.broadcasted_iota(jnp.int32, (NQ, W), 1)
         live = ((kpos[None] <= st[:, None, None] + qi[None])
                 & (kpos[None] >= fb[:, None, None])
-                & valid[:, None, None])  # (B, NQ, bs)
-        live = live[:, None]             # (B, 1, NQ, bs)
-        s = jnp.einsum("bknh,bskh->bkns", qt, kj,
+                & jnp.repeat(valid, bs, axis=1)[:, None])  # (B, NQ, W)
+        live = live[:, None]             # (B, 1, NQ, W)
+        s = jnp.einsum("bknh,bwkh->bknw", qt, kj,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(live, s, -1e30)
         m_cur = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.where(live, jnp.exp(s - m_cur[..., None]), 0.0)
         corr = jnp.exp(m - m_cur)
-        l = l * corr + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bkns,bskh->bknh", p.astype(vj.dtype), vj,
+        l_cur = l * corr + jnp.sum(p, axis=-1)
+        pv = jnp.einsum("bknw,bwkh->bknh", p.astype(vj.dtype), vj,
                         preferred_element_type=jnp.float32)
-        acc = acc * corr[..., None] + pv
-        return (m_cur, l, acc), None
+        acc_cur = acc * corr[..., None] + pv
+        keep = walk[:, None, None]
+        return (jnp.where(keep, m_cur, m), jnp.where(keep, l_cur, l),
+                jnp.where(keep[..., None], acc_cur, acc)), None
 
     m0 = jnp.full((B, K, NQ), -1e30, jnp.float32)
     l0 = jnp.zeros((B, K, NQ), jnp.float32)
     a0 = jnp.zeros((B, K, NQ, hd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(block, (m0, l0, a0),
-                                  jnp.arange(nb_slot, dtype=jnp.int32))
+    (m, l, acc), _ = jax.lax.scan(tile, (m0, l0, a0),
+                                  jnp.arange(T, dtype=jnp.int32))
     return (m.reshape(B, K, R, Sq), l.reshape(B, K, R, Sq),
             acc.reshape(B, K, R, Sq, hd))
 

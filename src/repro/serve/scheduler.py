@@ -121,6 +121,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.kernels import ops as kops
+from repro.kernels import paged_attention as kpa
 from repro.models import transformer as tf
 from repro.models.draft import Draft, make_draft
 from repro.obs import span
@@ -259,6 +260,11 @@ class EngineStats:
     spec_rounds: int = _stat("counter")
     spec_proposed: int = _stat("counter")
     spec_accepted: int = _stat("counter")
+    # key tiles the paged-attention kernel walks, of those its grid holds,
+    # per decode step and prefill chunk of the target model (one layer's
+    # call: every layer walks the same tiles)
+    attn_tiles_walked: int = _stat("counter")
+    attn_tiles_grid: int = _stat("counter")
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -272,6 +278,11 @@ class EngineStats:
     def mean_accepted_run(self) -> float:
         return ((self.spec_accepted + self.spec_rounds)
                 / max(self.spec_rounds, 1))
+
+    @property
+    def attn_tile_share(self) -> float:
+        """Share of the kernel's grid that the live bound lets through."""
+        return self.attn_tiles_walked / max(self.attn_tiles_grid, 1)
 
     @staticmethod
     def field_kinds() -> Dict[str, str]:
@@ -336,6 +347,11 @@ class EngineStats:
                 f"({self.spec_accepted}/{self.spec_proposed}), "
                 f"mean_run={self.mean_accepted_run:.2f} over "
                 f"{self.spec_rounds} rounds")
+        if self.attn_tiles_grid:
+            lines.append(
+                f"paged attention: tile_share={self.attn_tile_share:.3f} "
+                f"({self.attn_tiles_walked}/{self.attn_tiles_grid} key "
+                f"tiles walked)")
         return "\n".join(lines)
 
 
@@ -460,6 +476,8 @@ class SlotScheduler:
         self.spec_rounds = 0       # verify rounds run by speculating slots
         self.spec_proposed = 0     # draft tokens proposed in those rounds
         self.spec_accepted = 0     # draft tokens verified-and-emitted
+        self.attn_tiles_walked = 0  # see EngineStats.attn_tiles_*
+        self.attn_tiles_grid = 0
         self._queue: List[Request] = []
         self._slot_req: List[Optional[Request]] = [None] * B
         self._slot_out: List[List[int]] = [[] for _ in range(B)]
@@ -538,6 +556,7 @@ class SlotScheduler:
             # tables added below are per-slot constant state, not paged
             pool_bytes = sum(int(a.size) * int(a.dtype.itemsize)
                              for a in jax.tree.leaves(cache))
+            self._pool_itemsize = cache["kv"]["k"].dtype.itemsize
             self.alloc = BlockAllocator(nb, pool_bytes // nb)
             self.prefix_cache = SketchPrefixCache(
                 sv, allocator=self.alloc, block_size=self.block_size)
@@ -1006,6 +1025,7 @@ class SlotScheduler:
                       rows=len(seg)) as sp:
                 cache = self._prefill_one(cache, jnp.asarray(tok), table,
                                           off, slot, 0)
+            self._count_attn_tiles([off], [0], bucket, 1)
             if self.obs is not None:
                 self.obs.prefill_span(slot, off, len(seg), sp.dur_s)
             off += bucket
@@ -1057,6 +1077,7 @@ class SlotScheduler:
                 cache = self._prefill_one(cache, jnp.asarray(tok),
                                           jnp.array(row), off, slot,
                                           fold_base)
+            self._count_attn_tiles([off], [fold_base], bucket, 1)
             if self.obs is not None:
                 self.obs.prefill_span(slot, off, len(seg), sp.dur_s)
             # fold whole blocks that aged past the window ([0, end) keeps
@@ -1586,6 +1607,46 @@ class SlotScheduler:
             self._state = self._state._replace(tables=tables)
             self.alloc.unref(dead)
 
+    def _count_attn_tiles(self, start, fold_base, Sq: int,
+                          slots: int) -> None:
+        """Account kernel calls of ``Sq`` query rows over ``slots`` slot
+        rows of the grid, of which the slots at ``start`` / ``fold_base``
+        are live (a retired slot's dead table walks no tile).  Tile width
+        and bound come from the kernel's own ``tile_blocks`` /
+        ``tile_range``, so the counters cannot disagree with its walk."""
+        if not self.kernels:
+            return
+        cfg = self.cfg
+        K = cfg.num_kv_heads
+        nb = self.blocks_per_slot
+        P = kpa.tile_blocks(self.block_size, K, cfg.resolved_head_dim, nb,
+                            cfg.num_heads // K * Sq, self._pool_itemsize)
+        T = -(-nb // P)
+        lo, hi = kpa.tile_range(np.asarray(start), np.asarray(fold_base),
+                                Sq, P * self.block_size)
+        self.attn_tiles_walked += int(
+            np.maximum(np.minimum(hi, T - 1) - lo + 1, 0).sum())
+        self.attn_tiles_grid += slots * T
+
+    def _count_decode_tiles(self) -> None:
+        """The dispatched chunk's decode steps from the host mirrors: a
+        slot's step j attends from its position advanced by the steps it
+        has run (a verify round counts one committed token, a lower bound)."""
+        live = [s for s, r in enumerate(self._slot_req) if r is not None]
+        if not self.kernels or not live:
+            return
+        chunk = self.serve.decode_chunk
+        pos = np.asarray([self._slot_pos[s] for s in live])
+        left = np.asarray([self._slot_req[s].max_new
+                           - len(self._slot_out[s]) for s in live])
+        fb = np.asarray([self._slot_first_lblk[s] for s in live]) \
+            * self.block_size
+        steps = np.arange(chunk)[:, None]
+        self._count_attn_tiles(pos + np.minimum(steps, left),
+                               np.broadcast_to(fb, (chunk, fb.size)),
+                               self.spec_max + 1,
+                               chunk * self.serve.max_batch)
+
     @property
     def use_kernels(self) -> bool:
         """True when paged attention runs the Pallas kernel (compiled or
@@ -1701,6 +1762,7 @@ class SlotScheduler:
                 # sentinels enqueue AFTER the chunk in device-stream order,
                 # and any re-allocation's prefill writes enqueue later still
                 self._finish_folds(fold_host)
+            self._count_decode_tiles()
             self.decode_steps += self.serve.decode_chunk
             self._inflight = (toks, emits)
         return True
@@ -1920,6 +1982,8 @@ class SlotScheduler:
             spec_rounds=self.spec_rounds,
             spec_proposed=self.spec_proposed,
             spec_accepted=self.spec_accepted,
+            attn_tiles_walked=self.attn_tiles_walked,
+            attn_tiles_grid=self.attn_tiles_grid,
         )
         if self.is_kv:
             st.pool_blocks = self.num_blocks

@@ -17,7 +17,8 @@ from repro.kernels import ref
 from repro.kernels.count_sketch import count_sketch
 from repro.kernels.ops import (count_sketch_op, kernel_mode,
                                paged_attention_op, unsketch_op)
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (paged_attention, tile_blocks,
+                                           tile_range)
 from repro.kernels.unsketch import unsketch
 
 SHAPES = [(1, 64, 32), (4, 1000, 256), (2, 300, 64), (8, 4096, 512),
@@ -163,12 +164,17 @@ def test_kv_tail_scores_matches_ref():
 # paged attention (flash-decode over block tables)
 # ---------------------------------------------------------------------------
 
-# (B, Sq, K, R, hd, NB, bs, nb): decode-, verify- and chunk-shaped cases
+# (B, Sq, K, R, hd, NB, bs, nb): decode-, verify- and chunk-shaped cases;
+# the last four walk tables of several key tiles
 PAGED_SHAPES = [
     (3, 1, 2, 3, 16, 12, 8, 4),     # single-token decode, GQA
     (2, 4, 2, 2, 32, 10, 4, 5),     # speculative verify (C = 4)
     (1, 16, 1, 4, 16, 8, 8, 6),     # chunked prefill (one slot), MQA
     (4, 3, 3, 1, 8, 20, 16, 3),     # R == 1 (MHA-as-GQA degenerate)
+    (2, 1, 2, 3, 16, 400, 4, 160),  # decode, 5 tiles of 128 keys
+    (3, 4, 2, 2, 16, 300, 8, 96),   # verify, 3 tiles of 256 keys
+    (1, 16, 2, 2, 16, 200, 8, 96),  # chunk, 3 tiles of 256 keys
+    (1, 256, 1, 2, 16, 200, 8, 96),  # chunk in two blocks of 256 rows
 ]
 
 
@@ -203,16 +209,14 @@ def test_paged_attention_matches_ref(shape):
                                    rtol=1e-5, atol=1e-6, err_msg=name)
 
 
-def test_paged_attention_ragged_lengths_dense_oracle():
-    """Normalized kernel output vs a full-softmax f32 oracle computed
-    per slot over the gathered span — checks the mask semantics (per-row
-    causal bound, fold_base lower bound, dead blocks) rather than the
-    update equations."""
-    B, Sq, K, R, hd, NB, bs, nb = 2, 4, 2, 2, 16, 10, 4, 5
-    q, kp, vp, tables, start, fb = _paged_inputs(B, Sq, K, R, hd, NB, bs,
-                                                 nb, seed=5)
-    m, l, acc = paged_attention(q, kp, vp, tables, start, fb,
-                                interpret=True)
+def _assert_dense_oracle(q, kp, vp, tables, start, fb, stats):
+    """Normalized kernel output vs a full-softmax f32 oracle computed per
+    slot over the gathered span (per-row causal bound, fold_base lower
+    bound, dead blocks)."""
+    m, l, acc = stats
+    B, Sq, K, R, hd = q.shape
+    NB, bs = kp.shape[0], kp.shape[1]
+    nb = tables.shape[1]
     out = np.asarray(acc / jnp.maximum(l, 1e-30)[..., None])
     S = nb * bs
     kt = np.asarray(jnp.take(kp, tables, axis=0, mode="fill",
@@ -235,6 +239,87 @@ def test_paged_attention_ragged_lengths_dense_oracle():
                     o = (w @ vt[b, :, z]) / max(w.sum(), 1e-30)
                     np.testing.assert_allclose(out[b, z, r, i], o,
                                                rtol=2e-2, atol=2e-2)
+
+
+def test_paged_attention_ragged_lengths_dense_oracle():
+    """Normalized kernel output vs a full-softmax f32 oracle computed
+    per slot over the gathered span — checks the mask semantics (per-row
+    causal bound, fold_base lower bound, dead blocks) rather than the
+    update equations."""
+    B, Sq, K, R, hd, NB, bs, nb = 2, 4, 2, 2, 16, 10, 4, 5
+    args = _paged_inputs(B, Sq, K, R, hd, NB, bs, nb, seed=5)
+    _assert_dense_oracle(*args, paged_attention(*args, interpret=True))
+
+
+# slot 0's geometry in a table of 160 blocks of 4 rows, 5 tiles of 128
+# keys (slot 1 stays an ordinary mid-table slot): (Sq, start, fold_base,
+# entries of slot 0 marked dead, slot 0 retired)
+W_EDGE = 128
+EDGE_CASES = {
+    "ends_in_first_tile": (1, 5, 0, (), False),
+    "verify_ends_in_first_tile": (4, W_EDGE - 4, 0, (), False),
+    "reaches_last_entry": (4, 160 * 4 - 4, 0, (), False),
+    "fold_base_tiles_in": (1, 4 * W_EDGE + 20, 3 * W_EDGE + 5, (), False),
+    "dead_entry_in_live_tile": (4, 2 * W_EDGE + 10, 0, (33, 47), False),
+    "retired_slot": (1, 300, 0, tuple(range(160)), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EDGE_CASES))
+def test_paged_attention_tile_walk_edges(case):
+    """Edges of the live-tile walk, bitwise against the oracle's tile walk
+    and close to the dense full-softmax oracle: a range that ends inside
+    the first tile, one that reaches the table's last entry, a fold_base
+    several tiles in (the tiles below it are skipped), a dead entry inside
+    a live tile, and a retired slot (every entry dead, a stale start)."""
+    Sq, st0, fb0, dead, retired = EDGE_CASES[case]
+    B, K, R, hd, NB, bs, nb = 2, 2, 3, 16, 400, 4, 160
+    assert tile_blocks(bs, K, hd, nb, R * Sq) * bs == W_EDGE
+    q, kp, vp, tables, start, fb = _paged_inputs(B, Sq, K, R, hd, NB, bs,
+                                                 nb, seed=21)
+    tables = tables.at[0, np.asarray(dead, np.int32)].set(NB)
+    start = start.at[0].set(st0)
+    fb = fb.at[0].set(fb0).at[1].set(0)
+    args = (q, kp, vp, tables, start, fb)
+    got = paged_attention(*args, interpret=True)
+    want = ref.paged_attention_ref(*args)
+    for name, a, b in zip("m l acc".split(), got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    _assert_dense_oracle(*args, got)
+    if retired:
+        np.testing.assert_array_equal(np.asarray(got[1][0]), 0.0)
+        np.testing.assert_array_equal(np.asarray(got[2][0]), 0.0)
+
+
+@pytest.mark.parametrize("shape", PAGED_SHAPES[4:])
+def test_paged_attention_skips_dead_tiles(shape):
+    """A tile outside a slot's live range is never fetched: poisoning with
+    NaN every pool block that lies only in such tiles leaves the
+    statistics finite and bitwise unchanged.  A walk over every block of
+    the table fails this (0 * NaN in p @ v)."""
+    B, Sq, K, R, hd, NB, bs, nb = shape
+    q, kp, vp, tables, start, fb = _paged_inputs(*shape, seed=17)
+    tables = tables.at[0, nb - 1].set(tables[B - 1, 0])  # every entry real
+    fb = fb.at[B - 1].set(start[B - 1] // 2)           # tiles below skipped
+    base = paged_attention(q, kp, vp, tables, start, fb, interpret=True)
+    P = tile_blocks(bs, K, hd, nb, R * Sq)
+    lo, hi = tile_range(np.asarray(start), np.asarray(fb), Sq, P * bs)
+    tile_of = np.arange(nb) // P
+    walked = (tile_of[None] >= lo[:, None]) & (tile_of[None] <= hi[:, None])
+    tbl = np.asarray(tables)
+    poison = sorted(set(tbl[~walked].tolist()) - set(tbl[walked].tolist()))
+    assert poison, "fixture must leave whole tiles unwalked"
+    kp2, vp2 = np.asarray(kp, np.float32), np.asarray(vp, np.float32)
+    kp2[poison] = np.nan
+    vp2[poison] = np.nan
+    got = paged_attention(q, jnp.asarray(kp2, kp.dtype),
+                          jnp.asarray(vp2, vp.dtype), tables, start, fb,
+                          interpret=True)
+    for name, a, b in zip("m l acc".split(), base, got):
+        assert np.isfinite(np.asarray(b)).all(), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
 
 
 def test_paged_attention_invalid_rows_drop():
@@ -277,6 +362,17 @@ def test_paged_attention_decode_rows_bitwise_match_verify():
                                       np.asarray(lv[..., i]))
         np.testing.assert_array_equal(np.asarray(a1[..., 0, :]),
                                       np.asarray(av[..., i, :]))
+
+
+@pytest.mark.parametrize("R,C", [(8, 5), (3, 5), (2, 4)])
+def test_paged_attention_decode_and_verify_share_tile_width(R, C):
+    """Decode (Sq = 1) and speculative verify (Sq = C) pick one tile
+    width at both cells' shapes and the tests', so a verify row folds its
+    keys in the same tiles as the decode step at its position."""
+    for bs, K, hd, nb in [(16, 4, 128, 256), (16, 8, 128, 64),
+                          (4, 2, 32, 5), (8, 2, 16, 96)]:
+        assert tile_blocks(bs, K, hd, nb, R) == tile_blocks(bs, K, hd, nb,
+                                                            R * C)
 
 
 def test_paged_attention_ops_dispatch():

@@ -207,3 +207,55 @@ def test_paged_kernels_resolution(gemma):
             xcfg.serve, max_batch=2, max_seq=48, decode_chunk=4,
             paged_kernels=True))
     assert not sx.use_kernels
+
+
+def _tiles_meeting(lo_key, hi_key, W, T):
+    """Tiles of ``W`` keys, of ``T``, that hold a key of [lo_key, hi_key],
+    by brute force over each tile's keys."""
+    return sum(1 for t in range(T)
+               if set(range(t * W, (t + 1) * W))
+               & set(range(lo_key, hi_key + 1)))
+
+
+def test_attn_tile_counters_match_kernel_bound(gemma):
+    """EngineStats.attn_tiles_* of a small kernel-path run equal what the
+    kernel's tile width and live bound admit for every call, counted here
+    by brute force from the known geometry (two slots, one reaching its
+    table's second tile), and merge as counters."""
+    from repro.kernels.paged_attention import tile_blocks
+    from repro.serve.scheduler import EngineStats
+    cfg, params = gemma
+    bs, max_seq, bucket, chunk, max_new = 4, 160, 16, 4, 6
+    lens = [140, 20]
+    s, got = _run(cfg, params,
+                  _serve(cfg, paged_kernels=True, kv_block_size=bs,
+                         max_seq=max_seq, prefill_bucket=bucket,
+                         decode_chunk=chunk),
+                  _reqs(cfg, lens, max_new=max_new))
+    assert all(len(t) == max_new for t in got.values())
+    K, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    R, nb = cfg.num_heads // K, max_seq // bs
+    walked = grid = 0
+    P = tile_blocks(bs, K, hd, nb, R * bucket)
+    T = -(-nb // P)
+    for S in lens:                       # prefill chunks at 0, 16, ...
+        for off in range(0, S, bucket):
+            walked += _tiles_meeting(0, off + bucket - 1, P * bs, T)
+            grid += T
+    P = tile_blocks(bs, K, hd, nb, R)
+    T = -(-nb // P)
+    assert T == 2
+    for c in range(-(-max_new // chunk)):  # decode chunks, both slots
+        for j in range(chunk):
+            for S in lens:
+                pos = S - 1 + c * chunk + min(j, max_new - c * chunk)
+                walked += _tiles_meeting(0, pos, P * bs, T)
+            grid += len(lens) * T
+    st = s.stats()
+    assert (st.attn_tiles_walked, st.attn_tiles_grid) == (walked, grid)
+    assert 0 < st.attn_tile_share < 1
+    assert "tile_share" in st.format()
+    both = EngineStats.merge([st, st])
+    assert (both.attn_tiles_walked, both.attn_tiles_grid) == (2 * walked,
+                                                              2 * grid)
+    assert both.attn_tile_share == st.attn_tile_share

@@ -69,6 +69,27 @@ def test_paged_attention_compiles_for_v5e(one_chip, B, Sq):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# both cells' real kernel shapes (bench/cells): slots, table entries of
+# 16 rows, kv heads, query heads per kv head, query rows per slot
+@pytest.mark.parametrize("B,nb,K,R,Sq", [(14, 256, 4, 8, 1),
+                                         (18, 64, 8, 3, 1),
+                                         (1, 256, 4, 8, 256)],
+                         ids=["yi_decode", "chat_decode", "yi_chunk"])
+def test_paged_attention_compiles_at_cell_shapes(one_chip, B, nb, K, R, Sq):
+    """The tile width the kernel picks at the cells' shapes fits the
+    chip's VMEM: an overflow fails here, off the chip."""
+    hd, bs = 128, 16
+    NB = B * nb
+    args = (_sds((B, Sq, K, R, hd), jnp.bfloat16, one_chip),
+            _sds((NB, bs, K, hd), jnp.bfloat16, one_chip),
+            _sds((NB, bs, K, hd), jnp.bfloat16, one_chip),
+            _sds((B, nb), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip))
+    compiled = paged_attention.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_full_width_decode_step_holds_kernel(one_chip):
     """One full-width decode step through the compiled kernel: the
     program holds the Pallas call and its arguments fit one chip."""
