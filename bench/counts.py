@@ -5,7 +5,9 @@ These count what exact attention over the live positions needs (in a
 sketched cell, the exact window), not what a kernel's grid walks, so a
 kernel that skips dead blocks shows as a gain and not as a changed
 yardstick.  A multiply-add is two operations.  Weights, keys and values
-are bfloat16 (2 bytes).
+are bfloat16 (2 bytes).  The matrices of one layer are the
+configuration's module's count (``block_params``); attention is counted
+as the GQA paged kernel computes it.
 """
 from __future__ import annotations
 
@@ -28,13 +30,6 @@ def peak(device_kind: str) -> Dict[str, float]:
     return table[device_kind]
 
 
-def block_params(c: Dict) -> int:
-    """Matrix parameters of one decoder layer (attention + gated MLP)."""
-    d, H, K = c["d_model"], c["num_heads"], c["num_kv_heads"]
-    hd, ff = c["head_dim"], c["d_ff"]
-    return d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * ff
-
-
 def attn_flops(c: Dict, keys: float) -> float:
     """Attention operations of one query row over ``keys`` keys, all
     layers: q.k and p.v, each ``2 * head_dim`` per head and key."""
@@ -52,19 +47,19 @@ def attn_bytes(c: Dict, keys: float, rows: int) -> float:
     return c["num_layers"] * per_layer
 
 
-def decode_flops(c: Dict, tokens: int, key_sum: float) -> float:
+def decode_flops(block, c: Dict, tokens: int, key_sum: float) -> float:
     """``tokens`` decoded tokens whose queries attend ``key_sum`` keys in
-    all: the layers' matrices, the LM head over the vocabulary, and
-    attention."""
-    return (2.0 * tokens * (c["num_layers"] * block_params(c)
+    all: the layers' matrices (``block``, the configuration's module),
+    the LM head over the vocabulary, and attention."""
+    return (2.0 * tokens * (c["num_layers"] * block.block_params(c)
                             + c["d_model"] * c["vocab_size"])
             + attn_flops(c, key_sum))
 
 
-def prefill_flops(c: Dict, rows: int, key_sum: float) -> float:
+def prefill_flops(block, c: Dict, rows: int, key_sum: float) -> float:
     """``rows`` prompt rows through the layers (no logits: prefill
     produces none), attending ``key_sum`` keys in all."""
-    return (2.0 * rows * c["num_layers"] * block_params(c)
+    return (2.0 * rows * c["num_layers"] * block.block_params(c)
             + attn_flops(c, key_sum))
 
 

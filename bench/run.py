@@ -4,25 +4,30 @@
 
 A cell is a model configuration (``bench/configs/<config>.json``) under
 a traffic mix (``bench/traffic/<traffic>.json``), served with the
-geometry of ``bench/cells/<workload>.json``.  The run makes the weights
-from the seed on the device, builds the program's ``SlotScheduler``
-behind its ``AsyncServeEngine`` and drives that entry with the traffic,
-closed (a queue that never drains) or open loop (requests sent when
-due).  Set-up ends, and the measured window starts, at the first pump
-round boundary once the warm-up the cell file states is over (every
-compiled shape has run by then); ``--seconds`` later the run closes.
-The measured window is the whole admission cycles in between: it starts
-at the first boundary whose next round admits a request and ends at the
-last such boundary before ``--seconds`` run out, so that it never ends
-just before or just after a long admission round (a round that admits
-and prefills takes several times a decode round: one more or less at an
-edge moves a rate by percents).  Rates are taken over those whole rounds
-on the host clock, after each round's blocking collect.
+geometry of ``bench/cells/<workload>.json``.  Whatever depends on the
+model's block comes from the module the configuration file names under
+``"reference"`` (``bench/reference.py``, the Llama block, says what such
+a module provides): the program's config, the leaves of the weights, the
+float32 reference and the per-layer operation count.  The run makes the
+weights from the seed on the device, builds the program's
+``SlotScheduler`` behind its ``AsyncServeEngine`` and drives that entry
+with the traffic, closed (a queue that never drains) or open loop
+(requests sent when due).  Set-up ends, and the measured window starts,
+at the first pump round boundary once the warm-up the cell file states
+is over (every compiled shape has run by then); ``--seconds`` later the
+run closes.  The measured window is the whole admission cycles in
+between: it starts at the first boundary whose next round admits a
+request and ends at the last such boundary before ``--seconds`` run out,
+so that it never ends just before or just after a long admission round
+(a round that admits and prefills takes several times a decode round:
+one more or less at an edge moves a rate by percents).  Rates are taken
+over those whole rounds on the host clock, after each round's blocking
+collect.
 
-Then the program's state is freed and a plain float32 reference
-(``bench/reference.py``) scores a sample, drawn from the seed, of the
-requests the window finished: the widest gap by which a served token's
-logit lies below the reference's best decides ``correct``.
+Then the program's state is freed and the module's plain float32
+reference scores a sample, drawn from the seed, of the requests the
+window finished: the widest gap by which a served token's logit lies
+below the reference's best decides ``correct``.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics, read by ``bench/metrics/<name>.py`` from the host
@@ -42,6 +47,7 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -51,7 +57,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Any, Dict, List, Optional  # noqa: E402
+from typing import Any, Dict, List, Optional, Tuple  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -72,18 +78,6 @@ if __name__ == "__main__":
 
 import numpy as np  # noqa: E402
 
-# model keys of a configuration file (public config.json names) -> the
-# program's ModelConfig fields
-MODEL_KEYS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "norm_eps": "norm_eps",
-    "hidden_act": "act",
-}
-
 
 @dataclasses.dataclass
 class Cell:
@@ -95,12 +89,28 @@ class Cell:
     end_to_end: List[Dict]  # metric entries of BENCHMARK.json it reports
     per_layer: List[Dict]
     chips: int = 1
+    root: Path = ROOT       # the checkout the configuration's paths are in
+
+    @property
+    def block(self):
+        """The configuration's module, the file it names under
+        ``"reference"`` (required)."""
+        return load_module(self.root / self.config["reference"])
 
     @property
     def model(self) -> Dict:
-        """The configuration in the names the reference and counts use."""
-        return {v: self.config[k] for k, v in MODEL_KEYS.items()
-                if k in self.config}
+        """The configuration in the names the module and counts use."""
+        return self.block.model(self.config)
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path):
+    """The Python file at ``path``, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.stem.replace('-', '_').replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_cell(root: Path, workload: str) -> Cell:
@@ -122,7 +132,7 @@ def load_cell(root: Path, workload: str) -> Cell:
         cell=json.loads(
             (root / "bench" / "cells" / f"{workload}.json").read_text()),
         end_to_end=mine("end_to_end"), per_layer=mine("per_layer"),
-        chips=int(wl["chips"]))
+        chips=int(wl["chips"]), root=root)
 
 
 def reader(name: str, root: Path = ROOT):
@@ -132,11 +142,7 @@ def reader(name: str, root: Path = ROOT):
     for stem in (name, name.split(".")[0]):
         path = root / "bench" / "metrics" / f"{stem}.py"
         if path.exists():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return load_module(path).read
     raise FileNotFoundError(f"no reader for metric {name!r}")
 
 
@@ -401,31 +407,6 @@ def warm_release_shapes(sched) -> None:
             scratch.at[np.arange(n)].set(sched.num_blocks))
 
 
-# the program's dense block; a configuration file states its block in
-# these keys, and one that states another cannot be run by this program
-BLOCK = {"norm": "rmsnorm", "partial_rotary_factor": 1.0, "mlp_gated": True}
-
-
-def model_config(cell: Cell):
-    from repro.configs.base import ModelConfig, ServeConfig
-    for k, v in BLOCK.items():
-        if cell.config.get(k, v) != v:
-            raise SystemExit(f"{cell.config['name']}: {k} "
-                             f"{cell.config[k]!r}, but the program's "
-                             f"dense block has {v!r}")
-    m = cell.model
-    cfg = ModelConfig(name=cell.config["name"], family="dense",
-                      num_layers=m["num_layers"], d_model=m["d_model"],
-                      num_heads=m["num_heads"],
-                      num_kv_heads=m["num_kv_heads"], d_ff=m["d_ff"],
-                      vocab_size=m["vocab_size"], head_dim=m["head_dim"],
-                      act=m.get("act", "silu"),
-                      rope_theta=float(m["rope_theta"]),
-                      norm_eps=float(m["norm_eps"]))
-    serve = ServeConfig(**cell.cell["serve"])
-    return cfg, serve
-
-
 async def _drive(cell: Cell, sched, rec: Recorder, src, reqs: Dict
                  ) -> Dict:
     """Serve the request stream ``src`` through the async front-end
@@ -520,9 +501,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
 
     from bench import counts
-    from bench import reference as ref
     from bench import traffic
     from bench import weights as W
+    from repro.configs.base import ServeConfig
 
     devs = jax.devices()
     dev = devs[0]
@@ -542,11 +523,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         COMPILES_HOOKED.append(True)
 
     from repro.serve.scheduler import SlotScheduler
-    cfg, serve = model_config(cell)
-    model = cell.model
+    block, model = cell.block, cell.model
+    serve = ServeConfig(**cell.cell["serve"])
+    cfg = block.program_config(cell.config, serve)
     setup = {}
     t = time.perf_counter()
-    params = jax.block_until_ready(W.make_params(model, seed))
+    params = jax.block_until_ready(W.make_params(
+        block.leaf_specs(model), model["num_layers"], seed))
     setup["weights_s"] = time.perf_counter() - t
     t = time.perf_counter()
     sched = SlotScheduler(cfg, params, serve=serve)
@@ -602,13 +585,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     compared = {}
     if samples:
         t = time.perf_counter()
-        seqs, rows, toks = ref.served_rows(samples)
+        seqs, rows, toks = served_rows(samples)
         cols = [toks]
         if control:
-            cols.append(ref.logit_rows(model, seed, seqs, rows, toks,
-                                       mode="fp8")["argmax"])
-        r32 = ref.logit_rows(model, seed, seqs, rows, np.stack(cols, 1))
-        gap = float(ref.gaps(r32, 0).max())
+            cols.append(block.logit_rows(model, seed, seqs, rows, toks,
+                                         mode="fp8")["argmax"])
+        r32 = block.logit_rows(model, seed, seqs, rows, np.stack(cols, 1))
+        gap = float(gaps(r32, 0).max())
         ref_s = time.perf_counter() - t
         compared["logit_gap"] = {"value": gap,
                                  "limit": chk["limits"]["logit_gap"]}
@@ -687,9 +670,30 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if breakdown is not None:
         result["breakdown"] = breakdown
     if control and samples:
-        result["control"] = {"logit_gap": float(ref.gaps(r32, 1).max())}
+        result["control"] = {"logit_gap": float(gaps(r32, 1).max())}
     result["compared"] = compared
     return result
+
+
+def served_rows(samples: List[Tuple[np.ndarray, np.ndarray]]
+                ) -> Tuple[List[np.ndarray], List[Tuple[int, int]],
+                           np.ndarray]:
+    """(prompt, served tokens) pairs -> reference sequences, the rows
+    that score each served token (served token j of a prompt of length
+    S is scored by the logits at position S - 1 + j) and those tokens."""
+    seqs, rows, toks = [], [], []
+    for n, (prompt, out) in enumerate(samples):
+        seqs.append(np.concatenate([prompt, out[:-1]]).astype(np.int32))
+        S = len(prompt)
+        rows.extend((n, S - 1 + j) for j in range(len(out)))
+        toks.extend(int(t) for t in out)
+    return seqs, rows, np.asarray(toks, np.int32)
+
+
+def gaps(ref: Dict[str, np.ndarray], col: int = 0) -> np.ndarray:
+    """Per row, how far the reference's logit of token column ``col``
+    lies below its best, in standard deviations of that row's logits."""
+    return (ref["best"] - ref["at"][:, col]) / ref["std"]
 
 
 def use_cache() -> None:

@@ -1,10 +1,12 @@
-"""Operation and byte counts against hand counts; the peak table."""
+"""Operation and byte counts against hand counts, the layers' matrices
+through the Llama block's module; the peak table."""
 import json
 from pathlib import Path
 
 import pytest
 
 from bench import counts as C
+from bench import reference as ref
 
 ROOT = Path(__file__).resolve().parents[2]
 # a small dense GQA shape: 2 layers, d 8, 4 query heads and 2 kv heads of
@@ -15,7 +17,7 @@ SMALL = {"num_layers": 2, "d_model": 8, "num_heads": 4, "num_kv_heads": 2,
 
 def test_block_params_by_hand():
     # q 8x8, k and v 8x4 each, o 8x8, gate/up/down 8x16 x3
-    assert C.block_params(SMALL) == 64 + 32 + 32 + 64 + 3 * 128
+    assert ref.block_params(SMALL) == 64 + 32 + 32 + 64 + 3 * 128
 
 
 def test_key_sums_by_brute_force():
@@ -32,9 +34,9 @@ def test_decode_and_prefill_operations_by_hand():
     # operations, plus per layer and head 2*2 for q.k and 2*2 for p.v
     # over 5 keys
     want = 2 * (2 * 576 + 80) + 2 * 4 * (4 + 4) * 5
-    assert C.decode_flops(SMALL, 1, 5) == want
+    assert C.decode_flops(ref, SMALL, 1, 5) == want
     # 3 prompt rows at offset 0: keys 1 + 2 + 3, no LM head
-    assert C.prefill_flops(SMALL, 3, C.chunk_key_sum(0, 3)) == \
+    assert C.prefill_flops(ref, SMALL, 3, C.chunk_key_sum(0, 3)) == \
         2 * 3 * 2 * 576 + 2 * 4 * 8 * 6
 
 
